@@ -33,6 +33,8 @@ def main():
                     help="run a single benchmark")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (fedround_bench, fig2_clients_iid, fig3_energy,
                    fig4_noniid, kernel_bench, ledger_bench,
                    privacy_bench, roofline_table, scenario_bench,

@@ -24,22 +24,20 @@ import json  # noqa: E402
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, PartitionSpec as P  # noqa: E402
 
 import sys  # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "../../src"))
 
 from repro.core import solver  # noqa: E402
-from repro.roofline import (HW, cost_analysis_dict,  # noqa: E402
-                            parse_hlo_collectives)
-from repro.sharding import shard_map_compat  # noqa: E402
+from repro.roofline import HW, parse_hlo_collectives  # noqa: E402
 
 M = 8192 + 1          # command-r d_model + bias
 C = 8                 # outputs (identity activation ⇒ shared F, k=1)
 N_LOCAL = 2048        # samples per client/device
 PDEV = 256
 
-mesh = jax.make_mesh((PDEV,), ("data",))
+mesh = jax.make_mesh((PDEV,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def wire_svd(X, D):
@@ -74,14 +72,14 @@ def wire_gram(X, D, dtype=jnp.float32):
 def lower_and_measure(tag, fn):
     Xs = jax.ShapeDtypeStruct((PDEV * N_LOCAL, M), jnp.float32)
     Ds = jax.ShapeDtypeStruct((PDEV * N_LOCAL, C), jnp.float32)
-    sharded = shard_map_compat(fn, mesh=mesh,
-                               in_specs=(P("data", None), P("data", None)),
-                               out_specs=P(None, None))
+    sharded = jax.shard_map(fn, mesh=mesh,
+                            in_specs=(P("data", None), P("data", None)),
+                            out_specs=P(None, None), check_vma=False)
     compiled = jax.jit(sharded).lower(Xs, Ds).compile()
     colls = parse_hlo_collectives(compiled.as_text())
     coll_bytes = sum(v["bytes"] for v in colls.values())
     transit = sum(v["transit_bytes"] for v in colls.values())
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     rep = {
         "tag": tag,
         "collective_bytes_per_dev": coll_bytes,

@@ -77,6 +77,7 @@ from typing import Any, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from . import activations as acts
 from .contribution import (SelectSpec, accuracy_frontier,
@@ -88,12 +89,11 @@ from .faults import (CoordinatorKilled, FaultPlan, RoundFaults,
 from .ledger import FederationLedger
 from .scenario import ClientRoles, Scenario, Timeline
 from .topology import ExactFold, Topology, failover, simulate_round
-from .util import add_bias, as_2d
+from .util import add_bias, as_2d, enable_x64
 from .wire import Wire, _WireBase, get_wire
 from ..energy import EnergyMeter, watt_hours
 from ..energy.meter import J_PER_BYTE
 from ..obs.trace import NULL_TRACER
-from ..sharding import shard_map_compat
 
 TRANSPORTS = ("local", "mesh", "stream")
 
@@ -1329,7 +1329,6 @@ class FederationEngine:
         path — ring addition is order-independent, so ``W`` bit-matches
         the masked loop path.
         """
-        from jax.experimental import enable_x64
         priv, cw = self._priv, self._cw()
         sess = priv.session
         i0 = roles.participants[0] if roles.participants else 0
@@ -1491,7 +1490,6 @@ class FederationEngine:
         devices-for-memory trade the mesh makes (the bench's flat-in-P
         row therefore runs the local transport)."""
         import contextlib
-        from jax.experimental import enable_x64
         from jax.sharding import PartitionSpec as P
         wire = self.wire
         mesh = self.mesh or make_client_mesh(axis=self.axis)
@@ -1546,12 +1544,12 @@ class FederationEngine:
                 lambda s: P(self.axis, *([None] * (len(s.shape) - 1))),
                 template)
             ctx = contextlib.nullcontext()
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             jax.vmap(group_prog), mesh=mesh,
             in_specs=(P(self.axis, None, None, None),
                       P(self.axis, None, None, None),
                       P(self.axis, None)),
-            out_specs=out_specs)
+            out_specs=out_specs, check_vma=False)
         with ctx:
             wk = ("hier-mesh", mode, G, gsize, bound)
             if self.warmup and wk not in warmed:
@@ -1599,7 +1597,6 @@ class FederationEngine:
         (client uploads + one uplink per non-root aggregator).
         """
         import contextlib
-        from jax.experimental import enable_x64
         topo = self.topology
         P = len(parts_X)
         roles = self.scenario.roles(P)
@@ -1951,7 +1948,6 @@ class FederationEngine:
         and solves. Runs under x64 for the int64 limb algebra; the f32
         statistics are unchanged by it (weak typing, pinned by the
         conformance suite)."""
-        from jax.experimental import enable_x64
         from ..privacy import limbs as _limbs
         from jax.sharding import PartitionSpec as P
         from ..launch.mesh import masked_round_specs
@@ -1974,8 +1970,8 @@ class FederationEngine:
             return cw.mesh_reduce(cw.device_encode(st, pad[0]), axis)
 
         in_specs, out_specs = masked_round_specs(self.axis)
-        fn = shard_map_compat(shard_fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+        fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         with enable_x64():
             if self.warmup:
                 # untimed compile pass; it reuses this round's noise
@@ -2072,7 +2068,11 @@ class FederationEngine:
         X, D = pad_for_mesh(X, D, Pn, wire.act)
         lam, axis = self.lam, self.axis
 
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        # one row shard per device, placed before the collective program
+        # runs (it would otherwise start from one device's full copy)
+        rows = NamedSharding(mesh, P(axis, None))
+        X, D = jax.device_put(X, rows), jax.device_put(D, rows)
         if priv is not None and priv.masked:
             from ..privacy.policy import prefer_host_secagg
             if prefer_host_secagg(Pn):
@@ -2096,10 +2096,10 @@ class FederationEngine:
             def shard_fn(Xs, Ds):
                 return wire.mesh_reduce(wire.local_stats(Xs, Ds), axis)
 
-            fn = shard_map_compat(shard_fn, mesh=mesh,
-                                  in_specs=(P(self.axis, None),
-                                            P(self.axis, None)),
-                                  out_specs=out_specs)
+            fn = jax.shard_map(shard_fn, mesh=mesh,
+                               in_specs=(P(self.axis, None),
+                                         P(self.axis, None)),
+                               out_specs=out_specs, check_vma=False)
             if self.warmup:
                 jax.block_until_ready(fn(X, D))
             t0 = time.perf_counter()
@@ -2111,14 +2111,19 @@ class FederationEngine:
                 jax.block_until_ready(W)
             coordinator_time = time.perf_counter() - t0
         else:
-            def shard_fn(Xs, Ds):
-                st = wire.local_stats(Xs, Ds)
-                return wire.solve(wire.mesh_reduce(st, axis), lam)
+            key = ("mesh", wire, mesh)
+            if key not in self._fused_cache:
+                def shard_fn(Xs, Ds):
+                    st = wire.local_stats(Xs, Ds)
+                    return wire.solve(wire.mesh_reduce(st, axis), lam)
 
-            fn = shard_map_compat(shard_fn, mesh=mesh,
-                                  in_specs=(P(self.axis, None),
-                                            P(self.axis, None)),
-                                  out_specs=P(None, None))
+                # cached per engine, so a repeated round reuses the
+                # compiled collective program
+                self._fused_cache[key] = jax.jit(jax.shard_map(
+                    shard_fn, mesh=mesh,
+                    in_specs=(P(axis, None), P(axis, None)),
+                    out_specs=P(None, None), check_vma=False))
+            fn = self._fused_cache[key]
             if self.warmup:
                 # untimed compile pass at the real shapes, as on the
                 # other transports, so the timed collective is
@@ -2203,7 +2208,7 @@ def make_client_mesh(n_clients_axis: Optional[int] = None,
                      axis: str = "data"):
     """A 1-D mesh over all local devices for simulated-client sharding."""
     n = n_clients_axis or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def pad_for_mesh(X, D, Pn: int, act: str = "logistic"):

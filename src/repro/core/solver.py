@@ -189,12 +189,17 @@ def gram_stats_scan(X, fp, dbar, *, block: int = GRAM_BLOCK_N):
     fpc = fp.reshape(-1, block, k)
     dbc = dbar.reshape(-1, block, c)
 
+    # f32 statistics need f32 contractions: at the TPU's default
+    # precision the MXU rounds operands to bf16, which put W 1.3e-3 off
+    # the float64 solve at HIGGS size (chip_smoke.py phase c)
+    hi = jax.lax.Precision.HIGHEST
+
     def fold(carry, xs):
         G, mv = carry
         Xb, fb, db = xs
-        XF = jnp.einsum("nm,nk->knm", Xb, fb)
-        return (G + jnp.einsum("knm,knp->kmp", XF, XF),
-                mv + Xb.T @ (fb * fb * db)), None
+        XF = fb.T[:, :, None] * Xb[None]               # (k, block, m_b)
+        return (G + jnp.einsum("knm,knp->kmp", XF, XF, precision=hi),
+                mv + jnp.matmul(Xb.T, fb * fb * db, precision=hi)), None
 
     init = (jnp.zeros((k, mb, mb), X.dtype), jnp.zeros((mb, c), X.dtype))
     (G, mvec), _ = jax.lax.scan(fold, init, (Xc, fpc, dbc))

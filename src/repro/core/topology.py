@@ -338,8 +338,8 @@ class ExactFold:
         """One client's statistics → its ring element, host-side (the
         stream transport's per-client path; an edge bucket program
         emits the identical digits fused)."""
-        from jax.experimental import enable_x64
         from ..privacy import limbs as _limbs
+        from .util import enable_x64
         with enable_x64():
             enc = _limbs.encode_tree(self._wire.secagg_encode(stats),
                                      self.words)

@@ -6,6 +6,7 @@ engine layers and the solver all share this single pair now.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -19,3 +20,9 @@ def as_2d(D) -> jnp.ndarray:
     """Targets as ``(n, c)``: a 1-D label/target vector becomes one column."""
     D = jnp.asarray(D)
     return D[:, None] if D.ndim == 1 else D
+
+
+def enable_x64(on: bool = True):
+    """Context manager scoping ``jax_enable_x64`` (int64 limbs, float64
+    references): ``with enable_x64(): ...``."""
+    return jax.enable_x64(on)

@@ -7,41 +7,53 @@ accumulates the eq.-3 sufficient statistics:
     mvec += Xᵀ (fp² ⊙ d̄)        (m moment vector)
 
 TPU mapping (DESIGN.md §3): grid = (mi, mj, nk) with the sample axis nk
-innermost; each step loads two (bn × bm) tiles of X and a (bn × 1) tile of
-fp/d̄ into VMEM, scales, and feeds the MXU with a (bm × bn)·(bn × bm)
-contraction accumulated in the f32 VMEM output tile. Tile sizes are
-128-aligned for the MXU; the sample dimension streams HBM→VMEM so the
-working set stays at 3 tiles regardless of n (edge-device datasets stream
-at any size — the green-FL story on TPU).
+innermost. Every operand is laid out *feature-major*, samples on the lane
+axis: the wrappers hand the kernel ``Xᵀ`` (m_pad, n_pad) and the fp/d̄
+diagonals as (1, n_pad) rows. Each step loads two (tm × bn) tiles of Xᵀ
+and a (1 × bn) row of fp/d̄ into VMEM, scales the tiles along their lanes,
+and feeds the MXU with a (tm × bn)·(bn × tm) contraction accumulated in
+the f32 VMEM output tile. The sample dimension streams HBM→VMEM, so the
+working set stays at 3 tiles regardless of n (edge-device datasets
+stream at any size — the green-FL story on TPU).
 
-The moment vector reuses the already-resident X tile (j == 0 column of the
+Why feature-major: a TPU array's last two dimensions are stored in
+(8, 128) tiles, so a sample-major (n, 1) or (n, 29) operand occupies a
+full 128-lane row per sample in HBM — 128× (resp. 4.4×) its size, which
+does not fit a 100-client HIGGS fleet in 16 GB — and a (bn, 1) block out
+of an (n, c) array is refused by the TPU lowering outright. With samples
+on the lanes every block is (8, 128)-aligned and HBM holds ~what the data
+is. The feature tile is ``tm = bm`` when m > bm and the whole (8-rounded)
+feature axis otherwise, so a narrow table is never padded to 128 columns.
+The transposes in the wrappers are one O(n·m) copy against the kernel's
+O(k·n·m) reads.
+
+The moment row reuses the already-resident Xᵀ tile (j == 0 column of the
 grid), which is what "fused" buys over two separate passes.
 
-Four kernels share this mapping:
+One kernel carries this mapping, over a stacked, zero-padded
+(P, n_max, m) fleet (DESIGN.md §8): grid = (p, k, mi, mj, nk), with a
+client and an F-row dimension outermost. Step (p, f, ·) streams client
+p's Xᵀ tiles scaled by F row f and emits that row's (m, m) Gram, plus the
+moments of the d̄ rows it weights. ONE pallas_call therefore emits the
+whole federation's statistics while the VMEM working set stays at 3
+tiles per grid step — never the O(c·n·m) intermediate that the XLA
+``einsum("nm,nc->cnm", ...)`` reference path materializes. Its entry
+points:
 
-* ``gram_stats``       — the shared-F path (identity activation, k == 1):
-  one (m, m) Gram and one (m,) moment serve every output column.
-* ``gram_stats_multi`` — the per-output path (nonlinear activations,
-  k == c): grid = (c, mi, mj, nk) with a *leading output-class dimension*
-  (DESIGN.md §3.2). Each class step re-streams X but scales it by its own
-  f'(d̄_{:,cls}) column, so one pallas_call emits the full (c, m, m) Gram
-  stack and (m, c) moment block while the VMEM working set stays at 3
-  tiles per grid step — never the O(c·n·m) intermediate that the XLA
-  ``einsum("nm,nc->cnm", ...)`` reference path materializes.
-* ``gram_stats_shared`` — the shared-F path with a *c-column* moment
-  output: one Gram pass also emits ``mvec = Xᵀ d̄`` for every output
-  column (block (bn, c) of d̄ rides along with the already-resident X
-  tile), so the identity activation never needs a second dense read of X.
-* ``gram_stats_fleet`` / ``gram_stats_fleet_shared`` — the *fleet* axis
-  (DESIGN.md §8): a leading client grid dimension over a stacked,
-  zero-padded (P, n_max, m) input. grid = (p, c, mi, mj, nk) (resp.
-  (p, mi, mj, nk)), so ONE pallas_call emits the whole federation's
-  (P, c, m, m) Gram stack and (P, m, c) moments. Zero pad rows are exact
-  (they contribute nothing to either statistic), and each (p, cls) slice
-  runs the *same tile-shaped dot_generals in the same nk order* as the
-  per-client kernels — the fleet outputs are bitwise identical to P
-  separate per-client calls, which is what lets the batched engine path
-  bit-match the per-client loop (tests/test_fleet_batch.py).
+* ``gram_stats_fleet`` — the per-output path (nonlinear activations,
+  k == c, DESIGN.md §3.2): F row and d̄ row cls per class; (P, c, m, m)
+  Gram stack and (P, m, c) moments. X is re-read once per class.
+* ``gram_stats_fleet_shared`` — the shared-F path (identity activation,
+  k == 1): one F row, and all c d̄ rows ride along with the resident Xᵀ
+  tile, so the identity activation never needs a second dense read of X.
+* ``gram_stats_multi``, ``gram_stats_shared`` and ``gram_stats`` — their
+  one-client (P = 1) calls, so each fleet slice is bitwise what the
+  per-client call returns by construction (tests/test_fleet_batch.py).
+
+Zero pad rows are exact: they contribute nothing to either statistic.
+
+Every contraction asks for ``Precision.HIGHEST``: the statistics are f32
+end to end, and the TPU must not round the operands to bf16 on the MXU.
 """
 from __future__ import annotations
 
@@ -49,39 +61,36 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+# Xᵀ-tile · Xᵀ-tileᵀ (contract the sample/lane axis of both), and
+# row · Xᵀ-tileᵀ for the moment; f32 on the MXU.
+_NT = (((1,), (1,)), ((), ()))
+_HI = jax.lax.Precision.HIGHEST
+# a constant block index typed int32: a bare 0 turns int64 when the
+# kernel is traced under x64 (the masked round), which Mosaic refuses
+_Z = np.int32(0)
 
-def _kernel(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
-    nk = pl.program_id(2)
-    j = pl.program_id(1)
 
-    @pl.when(nk == 0)
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
+def _tiles(m: int, n: int, bm: int, bn: int):
+    """Padded extents and the feature tile: ``(m_pad, n_pad, tm)``. An
+    empty shard still gets one (all-zero) sample block, so its grid is
+    not empty and its statistics come out exactly zero."""
+    if m <= bm:
+        mp = -(-m // 8) * 8
+        tm = mp
+    else:
+        mp = -(-m // bm) * bm
+        tm = bm
+    return mp, max(-(-n // bn), 1) * bn, tm
 
-    # the (i, 0) moment tile is revisited at every j with nk == 0 — only
-    # the j == 0 pass may initialize it, or later j passes would re-zero it
-    @pl.when((nk == 0) & (j == 0))
-    def _init_m():
-        m_ref[...] = jnp.zeros_like(m_ref)
 
-    fp = fp_ref[...].astype(jnp.float32)          # (bn, 1)
-    xi = x_i_ref[...].astype(jnp.float32)         # (bn, bm)
-    xj = x_j_ref[...].astype(jnp.float32)
-    xfi = xi * fp
-    xfj = xj * fp
-    # MXU contraction over the sample tile
-    g_ref[...] += jax.lax.dot_general(
-        xfi, xfj, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == 0)
-    def _moment():
-        w = fp * fp * dbar_ref[...].astype(jnp.float32)   # (bn, 1)
-        m_ref[...] += jax.lax.dot_general(
-            xi, w, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+def _rows(A, np_):
+    """(..., n, k) sample-major → (..., k, n_pad) feature-major, zero-padded."""
+    A = jnp.swapaxes(A, -1, -2)
+    pad = [(0, 0)] * (A.ndim - 1) + [(0, np_ - A.shape[-1])]
+    return jnp.pad(A, pad)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -90,70 +99,13 @@ def gram_stats(X, fp, dbar, *, bm: int = 128, bn: int = 512,
     """X: (n, m); fp, dbar: (n,) → (G (m, m), mvec (m,)) float32.
 
     Pads n, m to tile multiples (zero rows/cols contribute nothing to
-    either statistic, so padding is exact).
+    either statistic, so padding is exact). A one-client
+    :func:`gram_stats_fleet_shared`.
     """
-    n, m = X.shape
-    mp = -(-m // bm) * bm
-    np_ = -(-n // bn) * bn
-    if (mp, np_) != (m, n):
-        X = jnp.pad(X, ((0, np_ - n), (0, mp - m)))
-        fp = jnp.pad(fp, (0, np_ - n))
-        dbar = jnp.pad(dbar, (0, np_ - n))
-    fp2 = fp[:, None]
-    dbar2 = dbar[:, None]
-    gi, gj, gk = mp // bm, mp // bm, np_ // bn
-
-    G, mvec = pl.pallas_call(
-        _kernel,
-        grid=(gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((bn, bm), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bn, bm), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn, 1), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j, k: (k, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, bm), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(X, X, fp2, dbar2)
-    return G[:m, :m], mvec[:m, 0]
-
-
-def _kernel_multi(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
-    nk = pl.program_id(3)
-    j = pl.program_id(2)
-
-    @pl.when(nk == 0)
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-
-    # the (cls, i) moment tile is revisited at every j with nk == 0 — only
-    # the j == 0 pass may initialize it (same hazard as the k=1 kernel)
-    @pl.when((nk == 0) & (j == 0))
-    def _init_m():
-        m_ref[...] = jnp.zeros_like(m_ref)
-
-    fp = fp_ref[...].astype(jnp.float32)          # (bn, 1): column cls of Fp
-    xi = x_i_ref[...].astype(jnp.float32)         # (bn, bm)
-    xj = x_j_ref[...].astype(jnp.float32)
-    xfi = xi * fp
-    xfj = xj * fp
-    g_ref[0] += jax.lax.dot_general(
-        xfi, xfj, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == 0)
-    def _moment():
-        w = fp * fp * dbar_ref[...].astype(jnp.float32)   # (bn, 1)
-        m_ref[...] += jax.lax.dot_general(
-            xi, w, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    G, mvec = gram_stats_fleet_shared(X[None], fp[None, :, None],
+                                      dbar[None, :, None], bm=bm, bn=bn,
+                                      interpret=interpret)
+    return G[0], mvec[0, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -166,72 +118,16 @@ def gram_stats_multi(X, Fp, Dbar, *, bm: int = 128, bn: int = 512,
     ``mvec[:, k] = Xᵀ (Fp[:, k]² ⊙ Dbar[:, k])`` — the eq.-3 sufficient
     statistics for every output class in one pallas_call.
 
-    Grid = (c, mi, mj, nk), class outermost (DESIGN.md §3.2): X tiles are
-    re-streamed per class with the per-class fp/d̄ column selected by the
-    leading grid index, so VMEM holds 3 tiles + one (bm, bm) accumulator
-    at any step regardless of n or c. Padding n, m to tile multiples is
-    exact (zero rows/cols contribute nothing to either statistic).
+    A one-client :func:`gram_stats_fleet`, grid = (1, c, mi, mj, nk),
+    class outermost (DESIGN.md §3.2): Xᵀ tiles are re-streamed per class
+    with the per-class fp/d̄ row selected by the class grid index, so
+    VMEM holds 3 tiles + one (tm, tm) accumulator at any step regardless
+    of n or c. Being the same kernel is what makes each fleet slice
+    bitwise the per-client result.
     """
-    n, m = X.shape
-    c = Fp.shape[1]
-    mp = -(-m // bm) * bm
-    np_ = -(-n // bn) * bn
-    if (mp, np_) != (m, n):
-        X = jnp.pad(X, ((0, np_ - n), (0, mp - m)))
-        Fp = jnp.pad(Fp, ((0, np_ - n), (0, 0)))
-        Dbar = jnp.pad(Dbar, ((0, np_ - n), (0, 0)))
-    gi, gj, gk = mp // bm, mp // bm, np_ // bn
-
-    G, mvec = pl.pallas_call(
-        _kernel_multi,
-        grid=(c, gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((bn, bm), lambda cls, i, j, k: (k, i)),
-            pl.BlockSpec((bn, bm), lambda cls, i, j, k: (k, j)),
-            pl.BlockSpec((bn, 1), lambda cls, i, j, k: (k, cls)),
-            pl.BlockSpec((bn, 1), lambda cls, i, j, k: (k, cls)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bm, bm), lambda cls, i, j, k: (cls, i, j)),
-            pl.BlockSpec((bm, 1), lambda cls, i, j, k: (i, cls)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c, mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((mp, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(X, X, Fp, Dbar)
-    return G[:, :m, :m], mvec[:m, :]
-
-
-def _kernel_shared(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
-    nk = pl.program_id(2)
-    j = pl.program_id(1)
-
-    @pl.when(nk == 0)
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-
-    @pl.when((nk == 0) & (j == 0))
-    def _init_m():
-        m_ref[...] = jnp.zeros_like(m_ref)
-
-    fp = fp_ref[...].astype(jnp.float32)          # (bn, 1): shared F diag
-    xi = x_i_ref[...].astype(jnp.float32)         # (bn, bm)
-    xj = x_j_ref[...].astype(jnp.float32)
-    xfi = xi * fp
-    xfj = xj * fp
-    g_ref[...] += jax.lax.dot_general(
-        xfi, xfj, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == 0)
-    def _moment():
-        # all c moment columns ride along with the resident X tile
-        w = fp * fp * dbar_ref[...].astype(jnp.float32)   # (bn, c)
-        m_ref[...] += jax.lax.dot_general(
-            xi, w, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    G, mvec = gram_stats_fleet(X[None], Fp[None], Dbar[None], bm=bm, bn=bn,
+                               interpret=interpret)
+    return G[0], mvec[0]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -242,71 +138,78 @@ def gram_stats_shared(X, fp, Dbar, *, bm: int = 128, bn: int = 512,
 
     The k = 1 Gram is identical to :func:`gram_stats`; the moment block
     carries every output column (``mvec[:, k] = Xᵀ (fp² ⊙ Dbar[:, k])``),
-    computed from the already-resident (bn, bm) X tile at j == 0. This is
+    computed from the already-resident (tm, bn) Xᵀ tile at j == 0. This is
     what closes the identity-activation gap where the fused kernel's
     single-column moment used to be discarded and ``Xᵀ d̄`` recomputed
-    densely (X is now read exactly once).
+    densely (X is now read exactly once). A one-client
+    :func:`gram_stats_fleet_shared`.
     """
-    n, m = X.shape
-    c = Dbar.shape[1]
-    mp = -(-m // bm) * bm
-    np_ = -(-n // bn) * bn
-    if (mp, np_) != (m, n):
-        X = jnp.pad(X, ((0, np_ - n), (0, mp - m)))
-        fp = jnp.pad(fp, (0, np_ - n))
-        Dbar = jnp.pad(Dbar, ((0, np_ - n), (0, 0)))
-    fp2 = fp[:, None]
-    gi, gj, gk = mp // bm, mp // bm, np_ // bn
-
-    G, mvec = pl.pallas_call(
-        _kernel_shared,
-        grid=(gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((bn, bm), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bn, bm), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn, 1), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((bn, c), lambda i, j, k: (k, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, bm), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, c), lambda i, j, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((mp, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(X, X, fp2, Dbar)
-    return G[:m, :m], mvec[:m, :]
+    G, mvec = gram_stats_fleet_shared(X[None], fp[None, :, None], Dbar[None],
+                                      bm=bm, bn=bn, interpret=interpret)
+    return G[0], mvec[0]
 
 
-def _kernel_fleet(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
-    nk = pl.program_id(4)
-    j = pl.program_id(3)
+def _kernel(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
+    """Grid step (p, f, i, j, s): ``G[p, f, i, j] += (xi·f)(xj·f)ᵀ``, and
+    at ``j == 0`` also ``M[p, f, :, i] += (f²·d̄)·xiᵀ`` — xi/xj client p's
+    (tm, bn) Xᵀ tiles, f its (1, bn) F row, d̄ the (r, bn) rows it weights."""
+    j, s = pl.program_id(3), pl.program_id(4)
 
-    @pl.when(nk == 0)
-    def _init():
+    @pl.when(s == 0)
+    def _init_g():
         g_ref[...] = jnp.zeros_like(g_ref)
 
-    @pl.when((nk == 0) & (j == 0))
+    # the (p, f, i) moment tile is revisited at every j with s == 0 — only
+    # the j == 0 pass may initialize it, or later j passes would re-zero it
+    @pl.when((s == 0) & (j == 0))
     def _init_m():
         m_ref[...] = jnp.zeros_like(m_ref)
 
-    fp = fp_ref[0].astype(jnp.float32)            # (bn, 1): col cls, client p
-    xi = x_i_ref[0].astype(jnp.float32)           # (bn, bm)
+    xi = x_i_ref[0].astype(jnp.float32)
     xj = x_j_ref[0].astype(jnp.float32)
-    xfi = xi * fp
-    xfj = xj * fp
+    f = fp_ref[0, 0].astype(jnp.float32)
     g_ref[0, 0] += jax.lax.dot_general(
-        xfi, xfj, (((0,), (0,)), ((), ())),
+        xi * f, xj * f, _NT, precision=_HI,
         preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _moment():
-        w = fp * fp * dbar_ref[0].astype(jnp.float32)     # (bn, 1)
-        m_ref[0] += jax.lax.dot_general(
-            xi, w, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        w = f * f * dbar_ref[0, 0].astype(jnp.float32)
+        m_ref[0, 0] += jax.lax.dot_general(
+            w, xi, _NT, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _fleet(Xs, Fps, DbT, np_, bm, bn, interpret):
+    """The one pallas_call: Xs (P, n, m); Fps (P, n, k) sample-major;
+    DbT (P, k, r, n_pad) already feature-major → (G (P, k, m, m),
+    moments (P, k, r, m)). Grid = (p, k, mi, mj, nk)."""
+    P, n, m = Xs.shape
+    _, k, r, _ = DbT.shape
+    mp, _, tm = _tiles(m, n, bm, bn)
+    XT = jnp.pad(jnp.swapaxes(Xs, 1, 2),
+                 ((0, 0), (0, mp - m), (0, np_ - n)))
+    FpT = _rows(Fps, np_)[:, :, None, :]               # (P, k, 1, n_pad)
+    gi, gk = mp // tm, np_ // bn
+    G, M = pl.pallas_call(
+        _kernel,
+        grid=(P, k, gi, gi, gk),
+        in_specs=[
+            pl.BlockSpec((1, tm, bn), lambda p, f, i, j, s: (p, i, s)),
+            pl.BlockSpec((1, tm, bn), lambda p, f, i, j, s: (p, j, s)),
+            pl.BlockSpec((1, 1, 1, bn), lambda p, f, i, j, s: (p, f, _Z, s)),
+            pl.BlockSpec((1, 1, r, bn), lambda p, f, i, j, s: (p, f, _Z, s)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, tm, tm), lambda p, f, i, j, s: (p, f, i, j)),
+            pl.BlockSpec((1, 1, r, tm), lambda p, f, i, j, s: (p, f, _Z, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((P, k, mp, mp), jnp.float32),
+            jax.ShapeDtypeStruct((P, k, r, mp), jnp.float32),
+        ],
+        interpret=interpret,
+    )(XT, XT, FpT, DbT)
+    return G[:, :, :m, :m], M[..., :m]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -318,73 +221,16 @@ def gram_stats_fleet(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
     mvec (P, m, c))`` float32 — ONE pallas_call for the whole federation.
 
     Grid = (p, c, mi, mj, nk), client outermost (DESIGN.md §8): every
-    (p, cls) slice replays exactly the (mi, mj, nk) schedule of
-    :func:`gram_stats_multi` on client p's rows, so the VMEM working set
-    stays 3 tiles + one (bm, bm) accumulator regardless of P, and each
-    client's output is bitwise what the per-client kernel produces.
-    Clients shorter than n_max are zero-padded (rows with fp = 0
-    contribute exactly nothing to either statistic).
+    (p, cls) slice streams client p's Xᵀ tiles scaled by its F row cls,
+    with d̄ row cls riding along for the moment, so the VMEM working set
+    stays 3 tiles + one (tm, tm) accumulator regardless of P. Clients
+    shorter than n_max are zero-padded (rows with fp = 0 contribute
+    exactly nothing to either statistic).
     """
-    P, n, m = Xs.shape
-    c = Fps.shape[2]
-    mp = -(-m // bm) * bm
-    np_ = -(-n // bn) * bn
-    if (mp, np_) != (m, n):
-        Xs = jnp.pad(Xs, ((0, 0), (0, np_ - n), (0, mp - m)))
-        Fps = jnp.pad(Fps, ((0, 0), (0, np_ - n), (0, 0)))
-        Dbars = jnp.pad(Dbars, ((0, 0), (0, np_ - n), (0, 0)))
-    gi, gj, gk = mp // bm, mp // bm, np_ // bn
-
-    G, mvec = pl.pallas_call(
-        _kernel_fleet,
-        grid=(P, c, gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((1, bn, bm), lambda p, cls, i, j, k: (p, k, i)),
-            pl.BlockSpec((1, bn, bm), lambda p, cls, i, j, k: (p, k, j)),
-            pl.BlockSpec((1, bn, 1), lambda p, cls, i, j, k: (p, k, cls)),
-            pl.BlockSpec((1, bn, 1), lambda p, cls, i, j, k: (p, k, cls)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bm, bm),
-                         lambda p, cls, i, j, k: (p, cls, i, j)),
-            pl.BlockSpec((1, bm, 1), lambda p, cls, i, j, k: (p, i, cls)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((P, c, mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((P, mp, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(Xs, Xs, Fps, Dbars)
-    return G[:, :, :m, :m], mvec[:, :m, :]
-
-
-def _kernel_fleet_shared(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
-    nk = pl.program_id(3)
-    j = pl.program_id(2)
-
-    @pl.when(nk == 0)
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-
-    @pl.when((nk == 0) & (j == 0))
-    def _init_m():
-        m_ref[...] = jnp.zeros_like(m_ref)
-
-    fp = fp_ref[0].astype(jnp.float32)            # (bn, 1): client p's mask
-    xi = x_i_ref[0].astype(jnp.float32)           # (bn, bm)
-    xj = x_j_ref[0].astype(jnp.float32)
-    xfi = xi * fp
-    xfj = xj * fp
-    g_ref[0] += jax.lax.dot_general(
-        xfi, xfj, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == 0)
-    def _moment():
-        w = fp * fp * dbar_ref[0].astype(jnp.float32)     # (bn, c)
-        m_ref[0] += jax.lax.dot_general(
-            xi, w, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    np_ = _tiles(Xs.shape[2], Xs.shape[1], bm, bn)[1]
+    G, M = _fleet(Xs, Fps, _rows(Dbars, np_)[:, :, None, :], np_, bm, bn,
+                  interpret)
+    return G, jnp.swapaxes(M[:, :, 0], 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -395,36 +241,10 @@ def gram_stats_fleet_shared(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
     ``(G (P, m, m), mvec (P, m, c))`` float32.
 
     The fleet analogue of :func:`gram_stats_shared`: grid =
-    (p, mi, mj, nk), one k = 1 Gram and a c-column moment per client in a
-    single pallas_call.
+    (p, 1, mi, mj, nk), one k = 1 Gram and a c-row moment block per client
+    in a single pallas_call.
     """
-    P, n, m = Xs.shape
-    c = Dbars.shape[2]
-    mp = -(-m // bm) * bm
-    np_ = -(-n // bn) * bn
-    if (mp, np_) != (m, n):
-        Xs = jnp.pad(Xs, ((0, 0), (0, np_ - n), (0, mp - m)))
-        Fps = jnp.pad(Fps, ((0, 0), (0, np_ - n), (0, 0)))
-        Dbars = jnp.pad(Dbars, ((0, 0), (0, np_ - n), (0, 0)))
-    gi, gj, gk = mp // bm, mp // bm, np_ // bn
-
-    G, mvec = pl.pallas_call(
-        _kernel_fleet_shared,
-        grid=(P, gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((1, bn, bm), lambda p, i, j, k: (p, k, i)),
-            pl.BlockSpec((1, bn, bm), lambda p, i, j, k: (p, k, j)),
-            pl.BlockSpec((1, bn, 1), lambda p, i, j, k: (p, k, 0)),
-            pl.BlockSpec((1, bn, c), lambda p, i, j, k: (p, k, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bm, bm), lambda p, i, j, k: (p, i, j)),
-            pl.BlockSpec((1, bm, c), lambda p, i, j, k: (p, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((P, mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((P, mp, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(Xs, Xs, Fps, Dbars)
-    return G[:, :m, :m], mvec[:, :m, :]
+    np_ = _tiles(Xs.shape[2], Xs.shape[1], bm, bn)[1]
+    G, M = _fleet(Xs, Fps, _rows(Dbars, np_)[:, None], np_, bm, bn,
+                  interpret)
+    return G[:, 0], jnp.swapaxes(M[:, 0], 1, 2)

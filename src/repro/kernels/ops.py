@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True`` (Pallas
-executes the kernel body in Python for correctness validation); on a TPU
-runtime, pass ``interpret=False`` (the default resolves by backend).
+Off-TPU the kernels run with ``interpret=True`` (Pallas executes the
+kernel body with XLA ops, for correctness validation); on a TPU they
+compile with Mosaic. ``interpret=None`` resolves by the default backend.
 """
 from __future__ import annotations
 
@@ -23,14 +23,10 @@ def client_gram_stats_fused(X, D_bar, Fp, *, interpret=None):
     X: (n, m) with bias column; D_bar: (n, c) pre-activation targets;
     Fp: (n, c) per-output diagonal of F. Returns (G (c, m, m), mvec (m, c)).
 
-    One pallas_call with a leading class grid dimension (DESIGN.md §3.2);
-    the c == 1 shared-F case takes the plain k=1 kernel.
+    One pallas_call with a leading class grid dimension (DESIGN.md §3.2),
+    the same kernel as the fleet path, so a fleet slice bit-matches it.
     """
     interpret = _default_interpret() if interpret is None else interpret
-    if Fp.ndim == 2 and Fp.shape[1] == 1:
-        G, mv = _gram.gram_stats(X, Fp[:, 0], D_bar[:, 0],
-                                 interpret=interpret)
-        return G[None], mv[:, None]
     return _gram.gram_stats_multi(X, Fp, D_bar, interpret=interpret)
 
 

@@ -1,13 +1,16 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import — jax locks the host
-# device count at first init. Everything else (tests, benchmarks) sees the
-# real single CPU device; only the dry-run builds the 512-device mesh.
+import sys
 
-import sys  # noqa: E402
-
-if "--devices" in sys.argv:  # test-scale override (before jax import!)
-    _n = sys.argv[sys.argv.index("--devices") + 1]
+if __name__ == "__main__":
+    # Run as a program (and so every per-combo child it spawns): a
+    # CPU-only tool on 512 simulated host devices (``--devices`` for a
+    # test-scale count), kept off any accelerator, which belongs to one
+    # process at a time. This MUST run before jax is imported — jax locks
+    # the host device count at first init. Importing the module (tests)
+    # changes neither, so importers keep their own devices.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    _n = sys.argv[sys.argv.index("--devices") + 1] \
+        if "--devices" in sys.argv else "512"
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_n}"
 
 import argparse          # noqa: E402
@@ -25,8 +28,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro import configs                                   # noqa: E402
 from repro.launch import mesh as mesh_lib                   # noqa: E402
 from repro.models import build_model, param_count           # noqa: E402
-from repro.roofline import (HW, cost_analysis_dict,         # noqa: E402
-                            parse_hlo_collectives, roofline_report)
+from repro.roofline import (HW, parse_hlo_collectives,     # noqa: E402
+                            roofline_report)
 from repro.sharding import specs as sh                      # noqa: E402
 from repro.train import init_train_state, make_train_step   # noqa: E402
 
@@ -161,7 +164,7 @@ def _lower_one(cfg, shape, kind, mesh, rules, cache_rules=None,
 def _cost_of(compiled) -> Dict[str, float]:
     """Per-device cost terms (XLA cost_analysis reports per-partition
     values with the 2mnk dot convention — calibrated, see EXPERIMENTS.md)."""
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = parse_hlo_collectives(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

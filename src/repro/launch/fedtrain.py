@@ -38,6 +38,7 @@ from repro.core.faults import CoordinatorKilled
 from repro.core.ledger import FederationLedger
 from repro.core.scenario import Scenario, Timeline
 from repro.data import partition, synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.privacy import PrivacyPolicy
 
 
@@ -149,6 +150,7 @@ def main():
     ap.add_argument("--lam", type=float, default=1e-3)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.timeline is not None and args.topology not in (None, "none", ""):
         raise SystemExit(

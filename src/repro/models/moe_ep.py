@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.specs import axis_size, current_rules, shard_map_compat
+from repro.sharding.specs import axis_size, current_rules
 from .layers import cast
 
 
@@ -133,9 +133,9 @@ def apply_moe_ep(x, p, cfg, *, dropless: bool = False
 
     bspec = P(baxes if len(baxes) > 1 else baxes[0], None, None)
     espec = P(ax, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(bspec, P(None, None), espec, espec, espec),
-        out_specs=(bspec, P()))
+        out_specs=(bspec, P()), check_vma=False)
     return fn(x, p["router"].astype(jnp.float32), cast(p["experts_wi"]),
               cast(p["experts_wg"]), cast(p["experts_wd"]))

@@ -90,7 +90,7 @@ def sensitivity(c: int, clip: float, act: str = "logistic",
     # the bound must hold for float64 statistics too — evaluate the
     # activation range in x64 (cheap, and an underestimated dmax from
     # a float32 eval would make Δ not an upper bound)
-    from jax.experimental import enable_x64
+    from ..core.util import enable_x64
     with enable_x64():
         z_lo = float(a.f_inv(jnp.float64(target_low)))
         z_hi = float(a.f_inv(jnp.float64(target_high)))

@@ -19,7 +19,7 @@ psum. This module is the same algebra as traceable JAX ops, bit-for-bit
   ``SecAggSession._carry`` as one ``lax.scan``), after which every limb
   is a clean base-2^32 digit and the host can decode.
 
-Everything here requires x64 mode (``jax.experimental.enable_x64`` —
+Everything here requires x64 mode (``repro.core.util.enable_x64`` —
 the engine wraps its masked programs in it): the lazy-carry
 representation needs genuine int64 headroom, and the encoding needs the
 full float64 mantissa. The f32 wire statistics themselves are
@@ -52,7 +52,7 @@ def require_x64(where: str = "limb ops") -> None:
     if not jax.config.jax_enable_x64:
         raise RuntimeError(
             f"{where} need int64 limbs: wrap the call in "
-            "jax.experimental.enable_x64() (the engine's masked fused/"
+            "repro.core.util.enable_x64() (the engine's masked fused/"
             "mesh programs do this for you)")
 
 

@@ -1,2 +1,2 @@
-from .analysis import (HW, collective_bytes_from_hlo, cost_analysis_dict,
+from .analysis import (HW, collective_bytes_from_hlo,
                        roofline_report, parse_hlo_collectives)

@@ -109,15 +109,6 @@ def collective_bytes_from_hlo(hlo_text: str) -> float:
     return sum(v["bytes"] for v in parse_hlo_collectives(hlo_text).values())
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across the API change: jax 0.4.x
-    returns a one-element list of dicts, newer jax the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def roofline_report(*, flops: float, bytes_accessed: float,
                     collective_bytes: float, chips: int,
                     model_flops: Optional[float] = None) -> Dict:

@@ -25,21 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Axes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_compat(fn, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the API move.
-
-    Newer jax exposes it as ``jax.shard_map(..., check_vma=)``; 0.4.x only
-    has ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    Replication checking is disabled either way (the collectives in our
-    shard_fns produce replicated outputs the checker can't always prove).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
 # default logical→mesh bindings; the launcher overrides "batch" with
 # ("pod", "data") on the multi-pod mesh.
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {

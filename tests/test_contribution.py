@@ -21,7 +21,7 @@ import math
 from contextlib import nullcontext
 
 import numpy as np
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 import jax.numpy as jnp
 import pytest
 

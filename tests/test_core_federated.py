@@ -11,7 +11,7 @@ Claims under test (paper §3–§4):
 """
 import numpy as np
 import jax
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 import jax.numpy as jnp
 import pytest
 

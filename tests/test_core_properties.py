@@ -1,7 +1,7 @@
 """Hypothesis property tests on the solver's algebraic invariants."""
 import numpy as np
 import jax
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 import jax.numpy as jnp
 import pytest
 

@@ -48,9 +48,11 @@ _SCRIPT = textwrap.dedent("""
     import os, sys, json
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import jax
+    from jax.sharding import AxisType
     from repro.launch.dryrun import lower_combo
     import repro.launch.mesh as mesh_lib
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = jax.make_mesh((4, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     import dataclasses
     import repro.configs as configs
     # reduced smoke configs on the small mesh, all three kinds

@@ -12,6 +12,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import numpy as np, jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro import configs
     from repro.models import moe as moe_mod
     from repro.models import moe_ep
@@ -20,7 +21,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.data.pipeline import make_batch
 
     cfg = configs.get("olmoe-1b-7b", smoke=True)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     p = moe_mod.init_moe(jax.random.PRNGKey(1), cfg)
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(4, 16, cfg.d_model)) * 0.5,

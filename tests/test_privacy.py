@@ -25,7 +25,7 @@ from contextlib import nullcontext
 
 import numpy as np
 import pytest
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 
 from repro.core import activations as acts
 from repro.core.engine import FederationEngine

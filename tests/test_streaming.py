@@ -1,7 +1,7 @@
 """Streaming-client equivalence (paper Fig. 1 + eq. 10)."""
 import numpy as np
 import jax
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 import jax.numpy as jnp
 
 from repro.core import activations as acts

@@ -22,7 +22,7 @@ dtypes, and partitions when hypothesis is installed.
 from contextlib import nullcontext
 
 import numpy as np
-from jax.experimental import enable_x64 as jax_enable_x64
+from repro.core.util import enable_x64 as jax_enable_x64
 import jax.numpy as jnp
 import pytest
 
