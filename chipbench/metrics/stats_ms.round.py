@@ -1,0 +1,8 @@
+"""Client-statistics time per round, in ms: the program's ``client.stats``
+and ``bucket.dispatch`` spans, each closed by ``block_until_ready``."""
+
+
+def read(rec):
+    if rec.unit != "round":
+        return None
+    return rec.span_ms("client.stats", "bucket.dispatch")
