@@ -661,22 +661,23 @@ class FederationEngine:
     # ------------------------------------------------------------ entry
     def run(self, parts_X: Sequence, parts_d: Sequence) -> RoundReport:
         """One round over pre-partitioned client data."""
-        if len(parts_X) != len(parts_d):
-            raise ValueError(
-                f"parts_X has {len(parts_X)} client shards but "
-                f"parts_d has {len(parts_d)}: every client needs one "
-                "feature shard and one target shard")
-        parts_d = [as_2d(d) for d in parts_d]
-        for i, (X, d) in enumerate(zip(parts_X, parts_d)):
-            nx, nd = int(np.shape(X)[0]), int(d.shape[0])
-            if nx != nd:
-                raise ValueError(
-                    f"client {i}: X has {nx} rows but d has {nd} — "
-                    "features and targets must pair rowwise")
-        self._fb = None
         with self.trace.span("round", transport=self.transport,
                              n_clients=len(parts_X),
                              fused=self.fused) as rsp:
+            with self.trace.span("round.prep"):
+                if len(parts_X) != len(parts_d):
+                    raise ValueError(
+                        f"parts_X has {len(parts_X)} client shards but "
+                        f"parts_d has {len(parts_d)}: every client needs "
+                        "one feature shard and one target shard")
+                parts_d = [as_2d(d) for d in parts_d]
+                for i, (X, d) in enumerate(zip(parts_X, parts_d)):
+                    nx, nd = int(np.shape(X)[0]), int(d.shape[0])
+                    if nx != nd:
+                        raise ValueError(
+                            f"client {i}: X has {nx} rows but d has {nd}"
+                            " — features and targets must pair rowwise")
+            self._fb = None
             if self.topology is not None:
                 # hierarchical round: the uploading units are the
                 # client shards on EVERY transport here — under a
@@ -764,50 +765,51 @@ class FederationEngine:
                 "the event-driven ledger path models membership as "
                 "explicit timeline events — score its registry "
                 "directly with core.contribution.loo_scores instead")
-        timeline = Timeline.parse(timeline) if isinstance(timeline, str) \
-            else timeline
-        P = len(parts_X)
-        if len(parts_d) != P:
-            raise ValueError("parts_X and parts_d length mismatch")
-        priv = self._begin_privacy(P)
-        if priv is not None:
-            # ledger membership changes after upload, so distributed
-            # noise shares fall back to the session universe (the
-            # cached run may carry a one-shot round's cohort) — see
-            # PrivacyRun.client_encode; shards are clipped per tick
-            # inside the metered client phase (_phase_stats)
-            priv.cohort = None
-        data = {i: (parts_X[i], as_2d(parts_d[i])) for i in range(P)}
-        if ledger is None:
-            ledger = FederationLedger(self._cw(), lam=self.lam)
-        elif priv is not None and priv.masked and \
-                getattr(ledger.wire, "session", None) is not priv.session:
-            # a masked federation's ledger must fold THIS run's ring
-            # elements — a float ledger (or one keyed to another
-            # session's pads) would silently de-anonymize or corrupt
-            raise ValueError(
-                "privacy=secagg needs a ledger on this run's masked "
-                "wire; pass ledger=None (the engine creates it) or "
-                "reuse the ledger from a previous run_events call of "
-                "this engine over the same client pool")
-        elif ledger.clients and max(ledger.clients) >= P:
-            # a restored federation must fit the current client pool —
-            # otherwise active clients would have no data to recompute
-            raise ValueError(
-                f"ledger has active clients up to id "
-                f"{max(ledger.clients)} but only {P} shards were given; "
-                "repartition with at least as many clients as the "
-                "checkpointed federation")
-        if revise_fn is None:
-            revise_fn = _default_revise
-        # `seen` (active ∪ departed) guards auto-admission: a continued
-        # run admits genuinely new clients at its first tick but never
-        # re-admits ones whose departure was an explicit event
-        sc_roles = self.scenario.roles(P)
-        schedule = timeline.schedule(P, roles=sc_roles,
-                                     joined=ledger.seen,
-                                     start=ledger.tick + 1)
-        ledger.tracer = self.trace
+        with self.trace.span("round.prep", n_clients=len(parts_X)):
+            timeline = Timeline.parse(timeline) \
+                if isinstance(timeline, str) else timeline
+            P = len(parts_X)
+            if len(parts_d) != P:
+                raise ValueError("parts_X and parts_d length mismatch")
+            priv = self._begin_privacy(P)
+            if priv is not None:
+                # ledger membership changes after upload, so distributed
+                # noise shares fall back to the session universe (the
+                # cached run may carry a one-shot round's cohort) — see
+                # PrivacyRun.client_encode; shards are clipped per tick
+                # inside the metered client phase (_phase_stats)
+                priv.cohort = None
+            data = {i: (parts_X[i], as_2d(parts_d[i])) for i in range(P)}
+            if ledger is None:
+                ledger = FederationLedger(self._cw(), lam=self.lam)
+            elif priv is not None and priv.masked and \
+                    getattr(ledger.wire, "session", None) is not priv.session:
+                # a masked federation's ledger must fold THIS run's ring
+                # elements — a float ledger (or one keyed to another
+                # session's pads) would silently de-anonymize or corrupt
+                raise ValueError(
+                    "privacy=secagg needs a ledger on this run's masked "
+                    "wire; pass ledger=None (the engine creates it) or "
+                    "reuse the ledger from a previous run_events call of "
+                    "this engine over the same client pool")
+            elif ledger.clients and max(ledger.clients) >= P:
+                # a restored federation must fit the current client pool —
+                # otherwise active clients would have no data to recompute
+                raise ValueError(
+                    f"ledger has active clients up to id "
+                    f"{max(ledger.clients)} but only {P} shards were given; "
+                    "repartition with at least as many clients as the "
+                    "checkpointed federation")
+            if revise_fn is None:
+                revise_fn = _default_revise
+            # `seen` (active ∪ departed) guards auto-admission: a continued
+            # run admits genuinely new clients at its first tick but never
+            # re-admits ones whose departure was an explicit event
+            sc_roles = self.scenario.roles(P)
+            schedule = timeline.schedule(P, roles=sc_roles,
+                                         joined=ledger.seen,
+                                         start=ledger.tick + 1)
+            ledger.tracer = self.trace
         reports = []
         for t, events in schedule:
             if t <= ledger.tick:
@@ -954,8 +956,8 @@ class FederationEngine:
         """Shared merge → (first solve →) solve tail, timed."""
         cw = self._cw()
         t0 = time.perf_counter()
-        with self.trace.span("merge", n_uploads=len(roles.on_time)):
-            agg = self._fold([stats[i] for i in roles.on_time])
+        with self.trace.span("merge", n_uploads=len(roles.on_time)) as sp:
+            agg = sp.ready(self._fold([stats[i] for i in roles.on_time]))
         W_first = None
         if roles.late:
             # first solve from the on-time group — a usable model — then
@@ -963,9 +965,10 @@ class FederationEngine:
             with self.trace.span("solve", first=True):
                 W_first = cw.solve(self._release(agg, salt=1), self.lam)
                 jax.block_until_ready(W_first)
-            with self.trace.span("merge", n_uploads=len(roles.late)):
+            with self.trace.span("merge", n_uploads=len(roles.late)) as sp:
                 for i in roles.late:
                     agg = cw.merge(agg, stats[i])
+                sp.ready(agg)
         with self.trace.span("solve"):
             W = cw.solve(self._release(agg, salt=0), self.lam)
             jax.block_until_ready(W)
@@ -1039,16 +1042,25 @@ class FederationEngine:
         np_dtype = np.dtype(getattr(self.wire, "dtype", np.float32))
         m_in = parts_X[idxs[0]].shape[1]
         c = parts_d[idxs[0]].shape[1]
-        mid = float(acts.get(self.wire.act).f(
-            jnp.zeros((), jnp.float32)))
-        Xs = np.zeros((len(idxs), bound, m_in), np_dtype)
-        Ds = np.full((len(idxs), bound, c), mid, np_dtype)
-        ns = np.zeros((len(idxs),), np.int32)
-        for row, i in enumerate(idxs):
-            n = int(parts_X[i].shape[0])
-            Xs[row, :n] = np.asarray(parts_X[i], np_dtype)
-            Ds[row, :n] = np.asarray(parts_d[i], np_dtype)
-            ns[row] = n
+        with self.trace.span("bucket.stack", bound=int(bound),
+                             n_clients=len(idxs)) as sp:
+            mid = float(acts.get(self.wire.act).f(
+                jnp.zeros((), jnp.float32)))
+            Xs = np.zeros((len(idxs), bound, m_in), np_dtype)
+            Ds = np.full((len(idxs), bound, c), mid, np_dtype)
+            ns = np.zeros((len(idxs),), np.int32)
+            for row, i in enumerate(idxs):
+                n = int(parts_X[i].shape[0])
+                Xs[row, :n] = np.asarray(parts_X[i], np_dtype)
+                Ds[row, :n] = np.asarray(parts_d[i], np_dtype)
+                ns[row] = n
+            if self.trace.enabled:
+                # pulled: the device-resident shards read back; built:
+                # the stacks the next dispatch uploads
+                pulled = sum(int(a.nbytes) for i in idxs
+                             for a in (parts_X[i], parts_d[i])
+                             if isinstance(a, jax.Array))
+                sp.set(bytes=pulled + Xs.nbytes + Ds.nbytes + ns.nbytes)
         return Xs, Ds, ns
 
     @staticmethod
@@ -1093,7 +1105,8 @@ class FederationEngine:
                                      cid=int(i)):
                     stats[i] = self._client_stats(parts_X[i],
                                                   parts_d[i])
-                    jax.block_until_ready(stats[i])
+                    with self.trace.span("client.wait", track="client"):
+                        jax.block_until_ready(stats[i])
                 time_by[i] = time_by.get(i, 0.0) + \
                     (time.perf_counter() - t0)
                 dispatches += 1
@@ -1109,7 +1122,9 @@ class FederationEngine:
                                          track="client", cid=int(i)):
                         stats[i] = self.wire.local_stats(parts_X[i],
                                                          parts_d[i])
-                        jax.block_until_ready(stats[i])
+                        with self.trace.span("client.wait",
+                                             track="client"):
+                            jax.block_until_ready(stats[i])
                     time_by[i] = time_by.get(i, 0.0) + \
                         (time.perf_counter() - t0)
                     dispatches += 1
@@ -1124,7 +1139,8 @@ class FederationEngine:
             with self.trace.span("bucket.dispatch", bound=int(bound),
                                  n_clients=len(b_idxs)):
                 batch = self.wire.local_stats_batch(Xs, Ds, ns)
-                jax.block_until_ready(batch)
+                with self.trace.span("client.wait"):
+                    jax.block_until_ready(batch)
             # a wire riding _WireBase's default batch (a per-client loop
             # over the stack) really dispatches once per client — keep
             # the dispatch metric honest for custom wires
@@ -1249,7 +1265,8 @@ class FederationEngine:
             with self.trace.span("bucket.dispatch", bound=int(bound),
                                  n_clients=len(idxs), fused=True):
                 out = fn(Xs, Ds, ns)
-                jax.block_until_ready(out)
+                with self.trace.span("client.wait"):
+                    jax.block_until_ready(out)
             dispatches += 1
             self._share_times(time_by, idxs, ns,
                               time.perf_counter() - t0)
@@ -1285,7 +1302,7 @@ class FederationEngine:
             peak = sum(self.wire.wire_bytes(a)
                        for a in on_aggs + late_aggs)
             t0 = time.perf_counter()
-            with self.trace.span("merge", n_uploads=len(on_aggs)):
+            with self.trace.span("merge", n_uploads=len(on_aggs)) as sp:
                 agg = self.wire.merge_many(on_aggs) if on_aggs else None
                 W_first = None
                 if agg is None:
@@ -1295,15 +1312,17 @@ class FederationEngine:
                     agg = self._fold([self.wire.local_stats(parts_X[i],
                                                             parts_d[i])
                                       for i in roles.on_time])
+                sp.ready(agg)
             if roles.late:
                 with self.trace.span("solve", first=True):
                     W_first = self.wire.solve(
                         self._release(agg, salt=1), self.lam)
                     jax.block_until_ready(W_first)
                 with self.trace.span("merge",
-                                     n_uploads=len(late_aggs)):
+                                     n_uploads=len(late_aggs)) as sp:
                     for st in late_aggs:
                         agg = self.wire.merge(agg, st)
+                    sp.ready(agg)
             with self.trace.span("solve"):
                 W = self.wire.solve(self._release(agg, salt=0),
                                     self.lam)
@@ -1365,7 +1384,8 @@ class FederationEngine:
                                      n_clients=len(idxs), fused=True,
                                      masked=True):
                     out = fn(Xs, Ds, ns, pads, keys)
-                    jax.block_until_ready(out)
+                    with self.trace.span("client.wait"):
+                        jax.block_until_ready(out)
             dispatches += 1
             self._share_times(time_by, idxs, ns,
                               time.perf_counter() - t0)
@@ -1402,17 +1422,18 @@ class FederationEngine:
         # element) is host-resident before the fold
         peak = (len(on_aggs) + len(late_aggs)) * sess.upload_bytes
         t0 = time.perf_counter()
-        with self.trace.span("merge", n_uploads=len(on_aggs)):
-            agg = cw.merge_many(on_aggs)
+        with self.trace.span("merge", n_uploads=len(on_aggs)) as sp:
+            agg = sp.ready(cw.merge_many(on_aggs))
         W_first = None
         if roles.late:
             with self.trace.span("solve", first=True):
                 W_first = cw.solve(self._release(agg, salt=1),
                                    self.lam)
                 jax.block_until_ready(W_first)
-            with self.trace.span("merge", n_uploads=len(late_aggs)):
+            with self.trace.span("merge", n_uploads=len(late_aggs)) as sp:
                 for st in late_aggs:
                     agg = cw.merge(agg, st)
+                sp.ready(agg)
         with self.trace.span("solve"):
             W = cw.solve(self._release(agg, salt=0), self.lam)
             jax.block_until_ready(W)
@@ -1731,7 +1752,8 @@ class FederationEngine:
                                      n_clients=len(b_idxs),
                                      fused=True, mode=mode):
                     out = fn(Xs, Ds, ns, *extra)
-                    jax.block_until_ready(out)
+                    with self.trace.span("client.wait"):
+                        jax.block_until_ready(out)
             dispatches += 1
             self._share_times(time_by, b_idxs, ns,
                               time.perf_counter() - t0)
@@ -1755,7 +1777,8 @@ class FederationEngine:
             with self.trace.span("client.stats", track="client",
                                  cid=int(i), mode=mode):
                 st = self._client_stats(parts_X[i], parts_d[i])
-                jax.block_until_ready(st)
+                with self.trace.span("client.wait", track="client"):
+                    jax.block_until_ready(st)
                 if mode == "exact":
                     st = folder.encode(st)
                 elif mode == "masked":

@@ -156,7 +156,8 @@ class FederationLedger:
         self._agg = None               # float aggregate / re-merge cache
         # flight-recorder hook (obs/, DESIGN.md §14): run_events points
         # this at the engine's tracer so membership changes land as
-        # ledger.* trace events; the default records nothing
+        # ledger.* trace events and snapshots as ledger.snapshot spans;
+        # the default records nothing
         from ..obs.trace import NULL_TRACER
         self.tracer = NULL_TRACER
 
@@ -270,7 +271,8 @@ class FederationLedger:
             raise ValueError(
                 "empty federation: no client ever joined")
         if self.exact:
-            return self._acc.snapshot()
+            with self.tracer.span("ledger.snapshot") as sp:
+                return sp.ready(self._acc.snapshot())
         if self._agg is None:          # non-subtractable wire: re-merge
             self._agg = self.wire.merge_tree(
                 [self.registry[c] for c in self.clients])

@@ -54,7 +54,7 @@ PROM_METRICS = (
 _HIST_BUCKETS = (1e-4, 1e-3, 1e-2, 0.1, 1.0, 10.0)
 
 # Perfetto track (tid) ordering: stable rows in the timeline UI.
-_TRACKS = ("coordinator", "client")
+_TRACKS = ("coordinator", "client", "host")
 
 
 def _tid(track: str) -> int:
@@ -171,18 +171,13 @@ def to_prometheus(tracer: Tracer,
     out.append("# HELP fed_round_cpu_seconds_total measured span CPU "
                "seconds by track")
     out.append("# TYPE fed_round_cpu_seconds_total counter")
-    # sum each track's *top-level work* spans: the shallowest non-round
-    # depth per track (coordinator work nests at depth 1 under the
-    # round span; client-track spans start at depth 0), so nested
-    # sub-spans never double-count
-    work = [s for s in tracer.spans if s.name != "round"]
-    min_depth: Dict[str, int] = {}
-    for sp in work:
-        d = min_depth.get(sp.track)
-        min_depth[sp.track] = sp.depth if d is None else min(d, sp.depth)
+    # sum each track's *top-level work* spans: those whose parent is a
+    # round span or none, so nested sub-spans never double-count
+    rounds = {s.id for s in tracer.spans if s.name == "round"}
     cpu_by_track: Dict[str, float] = {}
-    for sp in work:
-        if sp.depth == min_depth[sp.track]:
+    for sp in tracer.spans:
+        if sp.name != "round" and (sp.parent is None
+                                   or sp.parent in rounds):
             cpu_by_track[sp.track] = cpu_by_track.get(sp.track, 0.0) \
                 + sp.cpu_s
     for track, s in sorted(cpu_by_track.items()) or [("none", 0.0)]:

@@ -54,7 +54,8 @@ def test_span_taxonomy_pinned():
     assert SPAN_NAMES == (
         "round", "client.stats", "bucket.dispatch", "mask.encode",
         "collective", "tier.fold", "merge", "solve", "score.pass",
-        "ledger.apply")
+        "ledger.apply", "round.prep", "bucket.stack", "client.wait",
+        "ledger.snapshot", "gc")
 
 
 def test_event_taxonomy_pinned():
@@ -159,19 +160,35 @@ def test_sanitize_attrs_scalars_pass_arrays_raise():
     {},  # per-client loop
     {"fused": True},
     {"wire": "gram", "topology": "fanout=4,tiers=2"},  # tiered
-], ids=["loop", "fused", "tiered"])
+    # ragged shards: several buckets, then a merge that ends on its
+    # aggregate while traced
+    {"wire": "gram", "fused": True, "ragged": True},
+    {"wire": "gram", "batch_clients": True, "ragged": True},
+    # late joiners: W_first, then the late merge
+    {"wire": "gram", "scenario": Scenario.parse("late_join=0.25")},
+], ids=["loop", "fused", "tiered", "fused-ragged", "batched-ragged",
+        "loop-late"])
 def test_tracing_off_and_on_are_bit_identical(kw):
     """trace=None (the pre-PR default) and a live tracer produce the
     bitwise-same W and the same dispatch count: observation never
-    touches arrays, RNG state, or dispatch structure."""
+    touches arrays, RNG state, or dispatch structure (a traced merge
+    only waits for its aggregate)."""
+    kw = dict(kw)
     pX, pD = _parts(P=8)
+    if kw.pop("ragged", False):
+        pX = [X[:12 + 7 * i] for i, X in enumerate(pX)]
+        pD = [d[:12 + 7 * i] for i, d in enumerate(pD)]
     got = {}
     for traced in (False, True):
         eng = FederationEngine(trace=Tracer() if traced else None, **kw)
         r = eng.run(pX, pD)
-        got[traced] = (np.asarray(r.W).copy(), r.dispatches)
+        got[traced] = (np.asarray(r.W).copy(), r.dispatches,
+                       None if r.W_first is None
+                       else np.asarray(r.W_first).copy())
     assert np.array_equal(got[False][0], got[True][0])
     assert got[False][1] == got[True][1]
+    if got[False][2] is not None:
+        assert np.array_equal(got[False][2], got[True][2])
 
 
 # ------------------------------------------------- acceptance: P = 10³
