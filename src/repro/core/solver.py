@@ -206,16 +206,27 @@ def gram_stats_scan(X, fp, dbar, *, block: int = GRAM_BLOCK_N):
     return G, mvec
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("act", "add_bias", "dtype", "block"))
-def _gram_stats_xla(X, D, act="logistic", add_bias: bool = True,
-                    dtype=jnp.float32, block: int = GRAM_BLOCK_N):
-    """One jitted program per client shape: prep + chunked accumulation."""
+@functools.partial(jax.jit, static_argnames=("act", "add_bias", "dtype",
+                                             "backend", "interpret"))
+def _gram_stats(X, D, act, add_bias, dtype, backend, interpret):
+    """One jitted program per client shape: prep, statistics, casts."""
     X, d_bar, fp, act = _prep(X, D, act, add_bias, dtype)
-    fpk = jnp.ones((X.shape[0], 1), X.dtype) if act.name == "identity" \
-        else fp
-    G, m_vec = gram_stats_scan(X, fpk, d_bar, block=block)
-    return GramStats(G=G, m_vec=m_vec, n=jnp.asarray(X.shape[0], dtype))
+    if backend == "pallas":
+        from ..kernels import ops as _kops
+        if act.name == "identity":
+            # shared F = I: one kernel pass emits the Gram AND the full
+            # (m, c) moment block (kernels.gram_stats_shared)
+            G, m_vec = _kops.client_gram_stats_shared(X, d_bar,
+                                                      interpret=interpret)
+        else:
+            G, m_vec = _kops.client_gram_stats_fused(X, d_bar, fp,
+                                                     interpret=interpret)
+    else:
+        fpk = jnp.ones((X.shape[0], 1), X.dtype) \
+            if act.name == "identity" else fp
+        G, m_vec = gram_stats_scan(X, fpk, d_bar)
+    return GramStats(G=G.astype(dtype), m_vec=m_vec.astype(dtype),
+                     n=jnp.asarray(X.shape[0], dtype))
 
 
 def client_gram_stats(X, D, act="logistic", add_bias: bool = True,
@@ -223,11 +234,14 @@ def client_gram_stats(X, D, act="logistic", add_bias: bool = True,
                       interpret: Optional[bool] = None) -> GramStats:
     """Eq.-3 sufficient statistics of one client's local data.
 
-    ``backend`` selects how the per-output Gram stack is computed:
+    Either backend runs as ONE jitted program per client shape: the
+    activation prep, the statistics and the casts compile together, so
+    the host issues one dispatch per client. ``backend`` selects how the
+    per-output Gram stack is computed:
 
     * ``"xla"``    — :func:`gram_stats_scan`: a fixed-block ``lax.scan``
       accumulation (O(c·block·m) transient, never the O(c·n·m) blowup the
-      old einsum reference paid), jitted per client shape.
+      old einsum reference paid).
     * ``"pallas"`` — the fused streaming kernel
       (``kernels.gram_stats_multi``, or ``gram_stats_shared`` on the
       identity path, whose c-column moment output means X is read exactly
@@ -241,21 +255,14 @@ def client_gram_stats(X, D, act="logistic", add_bias: bool = True,
         backend = "xla"
     if backend == "pallas":
         from ..kernels import ops as _kops
-        X, d_bar, fp, act = _prep(X, D, act, add_bias, dtype)
-        if act.name == "identity":
-            # shared F = I: one kernel pass emits the Gram AND the full
-            # (m, c) moment block (kernels.gram_stats_shared)
-            G, m_vec = _kops.client_gram_stats_shared(X, d_bar,
-                                                      interpret=interpret)
-        else:
-            G, m_vec = _kops.client_gram_stats_fused(X, d_bar, fp,
-                                                     interpret=interpret)
-        return GramStats(G=G.astype(dtype), m_vec=m_vec.astype(dtype),
-                         n=jnp.asarray(X.shape[0], dtype))
-    if backend != "xla":
+        if interpret is None:
+            interpret = _kops._default_interpret()
+    elif backend == "xla":
+        interpret = False          # unused: keep one cache entry a shape
+    else:
         raise ValueError(f"unknown backend {backend!r}")
-    return _gram_stats_xla(X, _as_2d(jnp.asarray(D)), act=act,
-                           add_bias=add_bias, dtype=dtype)
+    return _gram_stats(X, D, act=act, add_bias=add_bias, dtype=dtype,
+                       backend=backend, interpret=interpret)
 
 
 def merge_gram(a: GramStats, b: GramStats) -> GramStats:

@@ -74,3 +74,26 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used <= HBM_BYTES, f"{case}: {used / 2**30:.2f} GiB"
+
+
+# one client's whole statistics pass (prep, kernel, casts) as the single
+# program ``client_gram_stats(backend="pallas")`` issues: (n, m, c)
+CLIENT_PASSES = {"higgs-silo": (77000, 28, 2), "mnist": (600, 784, 10)}
+
+
+@pytest.mark.parametrize("case", sorted(CLIENT_PASSES))
+def test_client_pass_compiles_for_v5e(one_chip, case):
+    import jax
+    import jax.numpy as jnp
+    from repro.core import solver
+    n, m, c = CLIENT_PASSES[case]
+    compiled = solver._gram_stats.lower(
+        jax.ShapeDtypeStruct((n, m), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n, c), jnp.float32, sharding=one_chip),
+        act="logistic", add_bias=True, dtype=jnp.float32, backend="pallas",
+        interpret=False).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used <= HBM_BYTES, f"{case}: {used / 2**30:.2f} GiB"
