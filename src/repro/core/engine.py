@@ -1063,6 +1063,16 @@ class FederationEngine:
                 sp.set(bytes=pulled + Xs.nbytes + Ds.nbytes + ns.nbytes)
         return Xs, Ds, ns
 
+    def _out_attrs(self, P: int, bound: int, m_in: int, c: int,
+                   fold: bool) -> dict:
+        """``bucket.dispatch``'s ``bytes_out``: the statistics bytes the
+        bucket program's fleet pass writes (per client, or once when it
+        folds); nothing for a wire that cannot say."""
+        count = getattr(self.wire, "fleet_out_bytes", None)
+        if count is None or not self.trace.enabled:
+            return {}
+        return {"bytes_out": int(count(P, bound, m_in, c, fold=fold))}
+
     @staticmethod
     def _share_times(time_by, idxs, ns, dt):
         """Attribute one bucket dispatch's wall time by sample share
@@ -1178,16 +1188,19 @@ class FederationEngine:
     def _fused_fn(self, with_solve: bool):
         """stats → leading-axis merge (→ solve) as ONE jitted program.
 
-        The stacked client buffers are donated (no-op on CPU, where XLA
-        does not implement donation) — at P=1000 the (P, n_max, m) stack
-        is the round's dominant allocation and the program may reuse it
-        in place.
+        The merge is the wire's folded fleet pass (``fleet_stats(...,
+        fold=True)``): on the gram wire's Pallas kernel the bucket's
+        clients accumulate in place, so the program never holds the
+        per-client statistics stack. The stacked client buffers are
+        donated (no-op on CPU, where XLA does not implement donation) —
+        at P=1000 the (P, n_max, m) stack is the round's dominant
+        allocation and the program may reuse it in place.
         """
         if with_solve not in self._fused_cache:
             wire, lam = self.wire, self.lam
 
             def prog(Xs, Ds, ns):
-                agg = wire.merge_axis(wire.fleet_stats(Xs, Ds, ns))
+                agg = wire.fleet_stats(Xs, Ds, ns, fold=True)
                 return wire.solve(agg, lam) if with_solve else agg
 
             donate = (0, 1) if jax.default_backend() != "cpu" else ()
@@ -1263,7 +1276,9 @@ class FederationEngine:
                                            bound)))
             t0 = time.perf_counter()
             with self.trace.span("bucket.dispatch", bound=int(bound),
-                                 n_clients=len(idxs), fused=True):
+                                 n_clients=len(idxs), fused=True,
+                                 **self._out_attrs(len(idxs), bound, m_in,
+                                                   c, fold=True)):
                 out = fn(Xs, Ds, ns)
                 with self.trace.span("client.wait"):
                     jax.block_until_ready(out)
@@ -1554,7 +1569,7 @@ class FederationEngine:
             ctx = enable_x64()
         else:
             def group_prog(Xg, Dg, ng):
-                return wire.merge_axis(wire.fleet_stats(Xg, Dg, ng))
+                return wire.fleet_stats(Xg, Dg, ng, fold=True)
 
             template = jax.eval_shape(
                 jax.vmap(group_prog),
@@ -1670,11 +1685,14 @@ class FederationEngine:
         i0 = roles.participants[0] if roles.participants else 0
         m_in = parts_X[i0].shape[1] if P else 0
         c = parts_d[i0].shape[1] if P else 1
-        template = self.wire.local_stats(
-            np.asarray(parts_X[i0])[:0], np.asarray(parts_d[i0])[:0])
         folder = sess = None
         share = 0.0
         cw = self._cw()
+        if mode != "float":
+            # the codecs size their rings from one (empty) client's
+            # statistics; the float fold needs no template pass
+            template = self.wire.local_stats(
+                np.asarray(parts_X[i0])[:0], np.asarray(parts_d[i0])[:0])
         if mode == "exact":
             folder = ExactFold(self.wire, template)
             agg_bytes = folder.agg_bytes
@@ -1715,8 +1733,10 @@ class FederationEngine:
             sa, sb = size_of(acc), size_of(sub)
             t0 = time.perf_counter()
             with self.trace.span("tier.fold", tier=int(level),
-                                 bytes=int(sa + sb)):
-                out = tier_add(acc, sub)
+                                 bytes=int(sa + sb)) as sp:
+                # traced, the span ends on the aggregate, not on the
+                # enqueue of its device add
+                out = sp.ready(tier_add(acc, sub))
             merge_s += time.perf_counter() - t0
             merges += 1
             meter.pop(sa)
@@ -1750,7 +1770,10 @@ class FederationEngine:
                 with self.trace.span("bucket.dispatch",
                                      bound=int(bound),
                                      n_clients=len(b_idxs),
-                                     fused=True, mode=mode):
+                                     fused=True, mode=mode,
+                                     **self._out_attrs(
+                                         len(b_idxs), bound, m_in, c,
+                                         fold=mode == "float")):
                     out = fn(Xs, Ds, ns, *extra)
                     with self.trace.span("client.wait"):
                         jax.block_until_ready(out)

@@ -277,12 +277,13 @@ def _fleet_mask(Xs, ns, dtype):
 
 @functools.partial(jax.jit, static_argnames=("act", "add_bias", "dtype",
                                              "backend", "block",
-                                             "interpret"))
+                                             "interpret", "fold"))
 def client_gram_stats_fleet(Xs, Ds, ns, act="logistic",
                             add_bias: bool = True, dtype=jnp.float32,
                             backend: str = "xla",
                             block: int = GRAM_BLOCK_N,
-                            interpret: Optional[bool] = None) -> GramStats:
+                            interpret: Optional[bool] = None,
+                            fold: bool = False) -> GramStats:
     """Eq.-3 statistics for a whole fleet of clients in ONE dispatch.
 
     ``Xs`` (P, n_max, m_in) stacked client shards, zero-padded on the
@@ -300,6 +301,12 @@ def client_gram_stats_fleet(Xs, Ds, ns, act="logistic",
     ``"xla"`` vmaps :func:`gram_stats_scan`. Either way each client's
     slice is bitwise identical to its per-client
     :func:`client_gram_stats` result on the same backend.
+
+    ``fold=True`` returns the fleet's sum instead, an unstacked
+    :class:`GramStats` (``G`` (k, m_b, m_b), ``m_vec`` (m_b, c), ``n``
+    the total count): on Pallas the kernel folds the clients in place
+    (``gram_stats_fleet(fold=True)``, one output block whatever P is),
+    on XLA the vmapped scan is summed over its client axis.
     """
     act = acts.get(act)
     if backend == "pallas" and jnp.dtype(dtype) != jnp.float32:
@@ -317,16 +324,19 @@ def client_gram_stats_fleet(Xs, Ds, ns, act="logistic",
     if backend == "pallas":
         from ..kernels import ops as _kops
         G, m_vec = _kops.client_gram_stats_fleet(
-            Xs, d_bar, fpk, shared=(act.name == "identity"),
+            Xs, d_bar, fpk, shared=(act.name == "identity"), fold=fold,
             interpret=interpret)
     elif backend == "xla":
         G, m_vec = jax.vmap(
             lambda x, f, d: gram_stats_scan(x, f, d, block=block))(
                 Xs, fpk, d_bar)
+        if fold:
+            G, m_vec = G.sum(axis=0), m_vec.sum(axis=0)
     else:
         raise ValueError(f"unknown backend {backend!r}")
+    n = ns.astype(dtype)
     return GramStats(G=G.astype(dtype), m_vec=m_vec.astype(dtype),
-                     n=ns.astype(dtype))
+                     n=n.sum() if fold else n)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "add_bias", "dtype"))

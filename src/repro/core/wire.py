@@ -21,9 +21,12 @@ representation of the paper's client statistics:
 
 The built-in wires additionally provide ``fleet_stats(Xs, Ds, ns)``
 (stacked statistics with a leading client axis, jit-traceable) and
-``merge_axis(stacked)`` (the merge over that leading axis) — the pair the
-engine's *fused* round path composes into a single stats → merge → solve
-program.
+``merge_axis(stacked)`` (the merge over that leading axis).
+``fleet_stats(..., fold=True)`` is the two at once — the fleet's merged
+statistics, which the gram wire's Pallas kernel folds in place without
+ever writing the per-client stack — and is what the engine's *fused*
+bucket programs (flat and per edge aggregator) compile into a single
+stats → merge (→ solve) program.
 
 Two implementations wrap ``core/solver.py``:
 
@@ -154,11 +157,13 @@ class SvdWire(_WireBase):
                                    add_bias=self.add_bias,
                                    dtype=self.dtype)
 
-    def fleet_stats(self, Xs, Ds, ns) -> ClientStats:
-        """Stacked Alg.-1 statistics, one batched-SVD dispatch."""
-        return solver.client_stats_fleet(Xs, Ds, ns, act=self.act,
-                                         add_bias=self.add_bias,
-                                         dtype=self.dtype)
+    def fleet_stats(self, Xs, Ds, ns, fold: bool = False) -> ClientStats:
+        """Stacked Alg.-1 statistics, one batched-SVD dispatch; with
+        ``fold`` their Iwen–Ong merge (:meth:`merge_axis`)."""
+        st = solver.client_stats_fleet(Xs, Ds, ns, act=self.act,
+                                       add_bias=self.add_bias,
+                                       dtype=self.dtype)
+        return self.merge_axis(st) if fold else st
 
     def local_stats_batch(self, Xs, Ds, ns) -> List[ClientStats]:
         st = self.fleet_stats(Xs, Ds, jnp.asarray(ns))
@@ -267,14 +272,34 @@ class GramWire(_WireBase):
                                         dtype=self.dtype,
                                         backend=self._backend())
 
-    def fleet_stats(self, Xs, Ds, ns) -> GramStats:
+    def fleet_stats(self, Xs, Ds, ns, fold: bool = False) -> GramStats:
         """Stacked eq.-3 statistics: ONE dispatch for the whole fleet
         (the Pallas fleet kernel on TPU, a vmapped ``lax.scan`` on XLA).
+        ``fold=True``: the fleet's sum, :meth:`merge_axis` of the stack,
+        which the Pallas kernel accumulates in place (one output block,
+        not P).
         """
         return solver.client_gram_stats_fleet(Xs, Ds, ns, act=self.act,
                                               add_bias=self.add_bias,
                                               dtype=self.dtype,
-                                              backend=self._backend())
+                                              backend=self._backend(),
+                                              fold=fold)
+
+    def fleet_out_bytes(self, P: int, n_max: int, m_in: int, c: int,
+                        fold: bool = False) -> int:
+        """Bytes of statistics a :meth:`fleet_stats` pass over a
+        (P, n_max, m_in) stack writes: the Pallas kernel's padded output
+        blocks, or the XLA scan's (k, m, m) and (m, c) results, per
+        client or once when it folds."""
+        mb = m_in + (1 if self.add_bias else 0)
+        k = self._k(c)
+        if self._backend() == "pallas" and \
+                jnp.dtype(self.dtype) == jnp.float32:
+            from ..kernels.gram_stats import fleet_out_bytes
+            return fleet_out_bytes(P, n_max, mb, k, c if k == 1 else 1,
+                                   fold=fold)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        return int((1 if fold else P) * (k * mb * mb + mb * c) * itemsize)
 
     def local_stats_batch(self, Xs, Ds, ns) -> List[GramStats]:
         st = self.fleet_stats(Xs, Ds, jnp.asarray(ns))

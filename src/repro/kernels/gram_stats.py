@@ -50,6 +50,14 @@ points:
   one-client (P = 1) calls, so each fleet slice is bitwise what the
   per-client call returns by construction (tests/test_fleet_batch.py).
 
+Both fleet entry points take ``fold=True``, the client-folding form for a
+caller that sums the stack at once (a fused bucket, an edge aggregator):
+grid = (k, mi, mj, p, nk) with ``p`` a reduction axis beside ``nk``, the
+output blocks indexed without ``p``, so the bucket's clients accumulate
+in place into one (k, m, m) block. What the kernel writes then does not
+grow with the bucket: at FEMNIST's k = 62, m = 785 one block is 199 MB
+padded, where 64 per-client blocks would be 12.7 GB.
+
 Zero pad rows are exact: they contribute nothing to either statistic.
 
 Every contraction asks for ``Precision.HIGHEST``: the statistics are f32
@@ -149,19 +157,28 @@ def gram_stats_shared(X, fp, Dbar, *, bm: int = 128, bn: int = 512,
     return G[0], mvec[0]
 
 
-def _kernel(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
+def _kernel(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref, *, fold):
     """Grid step (p, f, i, j, s): ``G[p, f, i, j] += (xi·f)(xj·f)ᵀ``, and
     at ``j == 0`` also ``M[p, f, :, i] += (f²·d̄)·xiᵀ`` — xi/xj client p's
-    (tm, bn) Xᵀ tiles, f its (1, bn) F row, d̄ the (r, bn) rows it weights."""
-    j, s = pl.program_id(3), pl.program_id(4)
+    (tm, bn) Xᵀ tiles, f its (1, bn) F row, d̄ the (r, bn) rows it weights.
 
-    @pl.when(s == 0)
+    With ``fold`` the grid is (f, i, j, p, s) and the output blocks drop
+    ``p``: every client of the bucket adds into the same resident (tm, tm)
+    block, which is zeroed at the first client's first sample block."""
+    if fold:
+        j, p, s = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+        first = (p == 0) & (s == 0)
+    else:
+        j, s = pl.program_id(3), pl.program_id(4)
+        first = s == 0
+
+    @pl.when(first)
     def _init_g():
         g_ref[...] = jnp.zeros_like(g_ref)
 
     # the (p, f, i) moment tile is revisited at every j with s == 0 — only
     # the j == 0 pass may initialize it, or later j passes would re-zero it
-    @pl.when((s == 0) & (j == 0))
+    @pl.when(first & (j == 0))
     def _init_m():
         m_ref[...] = jnp.zeros_like(m_ref)
 
@@ -179,10 +196,14 @@ def _kernel(x_i_ref, x_j_ref, fp_ref, dbar_ref, g_ref, m_ref):
             w, xi, _NT, precision=_HI, preferred_element_type=jnp.float32)
 
 
-def _fleet(Xs, Fps, DbT, np_, bm, bn, interpret):
+def _fleet(Xs, Fps, DbT, np_, bm, bn, interpret, fold=False):
     """The one pallas_call: Xs (P, n, m); Fps (P, n, k) sample-major;
     DbT (P, k, r, n_pad) already feature-major → (G (P, k, m, m),
-    moments (P, k, r, m)). Grid = (p, k, mi, mj, nk)."""
+    moments (P, k, r, m)). Grid = (p, k, mi, mj, nk).
+
+    ``fold``: grid = (k, mi, mj, p, nk), ``p`` a reduction axis beside
+    ``nk``; the outputs are the bucket's sums, (1, k, m, m) and
+    (1, k, r, m), so the kernel writes one block set whatever P is."""
     P, n, m = Xs.shape
     _, k, r, _ = DbT.shape
     mp, _, tm = _tiles(m, n, bm, bn)
@@ -190,31 +211,52 @@ def _fleet(Xs, Fps, DbT, np_, bm, bn, interpret):
                  ((0, 0), (0, mp - m), (0, np_ - n)))
     FpT = _rows(Fps, np_)[:, :, None, :]               # (P, k, 1, n_pad)
     gi, gk = mp // tm, np_ // bn
+    Po = 1 if fold else P
+
+    def at(index):              # index maps are written in (p, f, i, j, s)
+        return (lambda f, i, j, p, s: index(p, f, i, j, s)) if fold \
+            else index
+
     G, M = pl.pallas_call(
-        _kernel,
-        grid=(P, k, gi, gi, gk),
+        functools.partial(_kernel, fold=fold),
+        grid=(k, gi, gi, P, gk) if fold else (P, k, gi, gi, gk),
         in_specs=[
-            pl.BlockSpec((1, tm, bn), lambda p, f, i, j, s: (p, i, s)),
-            pl.BlockSpec((1, tm, bn), lambda p, f, i, j, s: (p, j, s)),
-            pl.BlockSpec((1, 1, 1, bn), lambda p, f, i, j, s: (p, f, _Z, s)),
-            pl.BlockSpec((1, 1, r, bn), lambda p, f, i, j, s: (p, f, _Z, s)),
+            pl.BlockSpec((1, tm, bn), at(lambda p, f, i, j, s: (p, i, s))),
+            pl.BlockSpec((1, tm, bn), at(lambda p, f, i, j, s: (p, j, s))),
+            pl.BlockSpec((1, 1, 1, bn),
+                         at(lambda p, f, i, j, s: (p, f, _Z, s))),
+            pl.BlockSpec((1, 1, r, bn),
+                         at(lambda p, f, i, j, s: (p, f, _Z, s))),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tm, tm), lambda p, f, i, j, s: (p, f, i, j)),
-            pl.BlockSpec((1, 1, r, tm), lambda p, f, i, j, s: (p, f, _Z, i)),
+            pl.BlockSpec((1, 1, tm, tm), at(
+                lambda p, f, i, j, s: (_Z if fold else p, f, i, j))),
+            pl.BlockSpec((1, 1, r, tm), at(
+                lambda p, f, i, j, s: (_Z if fold else p, f, _Z, i))),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((P, k, mp, mp), jnp.float32),
-            jax.ShapeDtypeStruct((P, k, r, mp), jnp.float32),
+            jax.ShapeDtypeStruct((Po, k, mp, mp), jnp.float32),
+            jax.ShapeDtypeStruct((Po, k, r, mp), jnp.float32),
         ],
         interpret=interpret,
     )(XT, XT, FpT, DbT)
     return G[:, :, :m, :m], M[..., :m]
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
+def fleet_out_bytes(P: int, n: int, m: int, k: int, r: int, *,
+                    fold: bool = False, bm: int = 128,
+                    bn: int = 512) -> int:
+    """Bytes the fleet kernel writes for P stacked (n, m) clients: its
+    padded (k, m_pad, m_pad) Gram and (k, r, m_pad) moment blocks, once
+    per client, or once for the whole stack when it folds."""
+    mp = _tiles(m, n, bm, bn)[0]
+    return 4 * (1 if fold else P) * k * (mp * mp + r * mp)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret",
+                                             "fold"))
 def gram_stats_fleet(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
-                     interpret: bool = False):
+                     interpret: bool = False, fold: bool = False):
     """Fleet-batched multi-output statistics over P stacked clients.
 
     Xs (P, n_max, m); Fps, Dbars (P, n_max, c) → ``(G (P, c, m, m),
@@ -226,19 +268,28 @@ def gram_stats_fleet(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
     stays 3 tiles + one (tm, tm) accumulator regardless of P. Clients
     shorter than n_max are zero-padded (rows with fp = 0 contribute
     exactly nothing to either statistic).
+
+    ``fold=True`` returns the stack's sums, ``(G (c, m, m), mvec (m, c))``:
+    grid = (c, mi, mj, p, nk), each client's tiles accumulate into the
+    same resident output block, so the kernel writes one (c, m, m) block
+    where the per-client form writes P of them (DESIGN.md §8).
     """
     np_ = _tiles(Xs.shape[2], Xs.shape[1], bm, bn)[1]
     G, M = _fleet(Xs, Fps, _rows(Dbars, np_)[:, :, None, :], np_, bm, bn,
-                  interpret)
+                  interpret, fold)
+    if fold:
+        return G[0], M[0, :, 0].T
     return G, jnp.swapaxes(M[:, :, 0], 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret",
+                                             "fold"))
 def gram_stats_fleet_shared(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
-                            interpret: bool = False):
+                            interpret: bool = False, fold: bool = False):
     """Fleet-batched shared-F statistics: Xs (P, n_max, m), Fps (P, n_max, 1)
     shared diag (1 on real rows, 0 on pads), Dbars (P, n_max, c) →
-    ``(G (P, m, m), mvec (P, m, c))`` float32.
+    ``(G (P, m, m), mvec (P, m, c))`` float32, or with ``fold=True`` their
+    sums over the stack, ``(G (m, m), mvec (m, c))``.
 
     The fleet analogue of :func:`gram_stats_shared`: grid =
     (p, 1, mi, mj, nk), one k = 1 Gram and a c-row moment block per client
@@ -246,5 +297,7 @@ def gram_stats_fleet_shared(Xs, Fps, Dbars, *, bm: int = 128, bn: int = 512,
     """
     np_ = _tiles(Xs.shape[2], Xs.shape[1], bm, bn)[1]
     G, M = _fleet(Xs, Fps, _rows(Dbars, np_)[:, None], np_, bm, bn,
-                  interpret)
+                  interpret, fold)
+    if fold:
+        return G[0, 0], M[0, 0].T
     return G[:, 0], jnp.swapaxes(M[:, 0], 1, 2)

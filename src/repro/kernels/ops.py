@@ -47,7 +47,7 @@ def client_gram_stats_shared(X, D_bar, fp=None, *, interpret=None):
 
 
 def client_gram_stats_fleet(Xs, D_bars, Fps, *, shared: bool = False,
-                            interpret=None):
+                            fold: bool = False, interpret=None):
     """Fleet-batched client statistics: one pallas_call for P clients.
 
     Xs: (P, n_max, m) stacked, zero-padded client data (bias column
@@ -55,14 +55,16 @@ def client_gram_stats_fleet(Xs, D_bars, Fps, *, shared: bool = False,
     (P, n_max, c) per-output F diagonals, or (P, n_max, 1) with
     ``shared=True`` for the shared-F path (1 on real rows, 0 on pads).
     Returns (G (P, k, m, m), mvec (P, m, c)) with k = c (per-output) or
-    k = 1 (shared).
+    k = 1 (shared); with ``fold=True`` their sums over the P clients,
+    (G (k, m, m), mvec (m, c)), folded inside the kernel.
     """
     interpret = _default_interpret() if interpret is None else interpret
     if shared:
-        G, mv = _gram.gram_stats_fleet_shared(Xs, Fps, D_bars,
+        G, mv = _gram.gram_stats_fleet_shared(Xs, Fps, D_bars, fold=fold,
                                               interpret=interpret)
-        return G[:, None], mv
-    return _gram.gram_stats_fleet(Xs, Fps, D_bars, interpret=interpret)
+        return (G[None] if fold else G[:, None]), mv
+    return _gram.gram_stats_fleet(Xs, Fps, D_bars, fold=fold,
+                                  interpret=interpret)
 
 
 def decode_gqa(q, k, v, kv_len, *, interpret=None, block_s: int = 512):

@@ -75,7 +75,9 @@ def _soft_targets(y, c: int) -> np.ndarray:
 def reference_solve(X, y, c: int = N_CLASSES, lam: float = LAM):
     """Float64 centralized eq.-3 solve, plain numpy: for every class k,
     ``(Xᵀ F_k² X + λI) w_k = Xᵀ (F_k² d̄_k)`` with the bias column first,
-    ``d̄ = logit(D)`` and ``F = diag(f'(d̄)) = diag(D(1 − D))``."""
+    ``d̄ = logit(D)`` and ``F = diag(f'(d̄)) = diag(D(1 − D))``. Any number
+    of classes ``c`` (the tiered FEMNIST-shape test solves 62); one dense
+    ``n × m`` pass per class, so it is for test sizes."""
     X = np.asarray(X, np.float64)
     Xb = np.concatenate([np.ones((X.shape[0], 1)), X], axis=1)
     D = _soft_targets(np.asarray(y), c)

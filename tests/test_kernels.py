@@ -217,6 +217,45 @@ def test_gram_stats_fleet_shared_bitmatches_per_client():
         assert np.array_equal(np.asarray(mv[i]), np.asarray(mvi))
 
 
+@pytest.mark.parametrize("shared", [False, True], ids=["k62", "shared"])
+def test_gram_stats_fleet_fold_sums_the_clients(shared):
+    """The client-folding grid (k, mi, mj, p, nk) writes the sum of what
+    the per-client grid writes, on ragged shards (pad rows) with an empty
+    pad client, at FEMNIST's k = 62 with a small m (the shared-F path:
+    k = 1 and a 62-column moment)."""
+    rng = np.random.default_rng(15)
+    m, c = 20, 62
+    k = 1 if shared else c
+    ns = [512, 301, 0, 77, 450]
+    npad = 640               # two sample blocks: nk > 1 inside each client
+    Xs = np.zeros((len(ns), npad, m), np.float32)
+    Fps = np.zeros((len(ns), npad, k), np.float32)
+    Dbs = np.zeros((len(ns), npad, c), np.float32)
+    for i, n in enumerate(ns):
+        Xs[i, :n] = rng.normal(size=(n, m))
+        Fps[i, :n] = 1.0 if shared else rng.uniform(0.04, 0.25, (n, k))
+        Dbs[i, :n] = rng.normal(size=(n, c))
+    fleet = gram_stats_fleet_shared if shared else gram_stats_fleet
+    args = (jnp.asarray(Xs), jnp.asarray(Fps), jnp.asarray(Dbs))
+    G, mv = fleet(*args, interpret=True)
+    Gf, mvf = fleet(*args, interpret=True, fold=True)
+    assert Gf.shape == G.shape[1:] and mvf.shape == (m, c)
+    # float32 sums in another order: the folded block adds every
+    # client's tiles into one accumulator, the per-client form sums
+    # whole blocks after; over at most a bucket of clients the two stay
+    # within a few float32 ulps of the largest entry (2**-23 ≈ 1.2e-7)
+    for got, per in ((Gf, G), (mvf, mv)):
+        want = np.asarray(per, np.float64).sum(0)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                                   atol=1e-6 * np.abs(want).max())
+    # the pad client and the pad rows add exactly nothing
+    keep = [i for i, n in enumerate(ns) if n]
+    G2, mv2 = fleet(*(a[np.asarray(keep)] for a in args), interpret=True,
+                    fold=True)
+    assert np.array_equal(np.asarray(G2), np.asarray(Gf))
+    assert np.array_equal(np.asarray(mv2), np.asarray(mvf))
+
+
 # ----------------------------------------------------------- decode attn
 @pytest.mark.parametrize("b,hq,hkv,hd,S", [
     (2, 8, 2, 64, 1024), (1, 9, 3, 64, 513), (2, 16, 16, 128, 300),

@@ -5,6 +5,7 @@ the loop, batched, fused and event-driven paths, the host-time spans
 that put every span on the device trace's clock."""
 import gc
 import glob
+import time
 
 import jax
 import jax.numpy as jnp
@@ -189,3 +190,45 @@ def test_profiler_trace_holds_the_program_spans(tmp_path):
              for line in plane.lines for e in line.events}
     assert {"round", "round.prep", "client.stats", "client.wait", "merge",
             "solve"} <= names
+
+
+def test_float_tier_fold_span_ends_on_its_aggregate():
+    """Traced, each ``tier.fold`` of the float fold waits for its device
+    add: the span holds one block on the merged statistics and ends after
+    it, not on the add's enqueue."""
+    from repro.core.solver import GramStats
+    pX, pD = _parts([40, 9, 70, 33, 120, 5])
+    tr = Tracer()
+    blocked = []
+    block = tr._block
+
+    def recording(x):
+        out = block(x)
+        blocked.append((time.perf_counter() - tr.t_origin, x))
+        return out
+    tr._block = recording
+    FederationEngine(wire="gram", trace=tr,
+                     topology="fanout=2,tiers=3,exact=off").run(pX, pD)
+    folds = tr.spans_named("tier.fold")
+    assert {s.attrs["tier"] for s in folds} == {0, 1, 2}
+    for sp in folds:
+        inside = [x for t, x in blocked if sp.t0 <= t <= sp.t0 + sp.dur_s]
+        assert len(inside) == 1 and isinstance(inside[0], GramStats)
+
+
+@pytest.mark.parametrize("exact", ["off", "on"])
+def test_tier_bucket_dispatch_counts_the_bytes_it_writes(exact):
+    """``bucket.dispatch``'s ``bytes_out``: the float fold's bucket
+    program writes one folded block of statistics, the exact codec's one
+    per member (each is encoded before the ring sum)."""
+    pX, pD = _parts([40, 9, 70, 33, 120, 5])
+    m, c = pX[0].shape[1] + 1, pD[0].shape[1]
+    tr = Tracer()
+    FederationEngine(wire="gram", trace=tr,
+                     topology=f"fanout=3,tiers=2,exact={exact}").run(pX, pD)
+    one = 4 * (c * m * m + m * c)         # XLA backend: unpadded float32
+    spans = tr.spans_named("bucket.dispatch")
+    assert spans
+    for sp in spans:
+        per = 1 if exact == "off" else sp.attrs["n_clients"]
+        assert sp.attrs["bytes_out"] == per * one

@@ -16,7 +16,7 @@ import pytest
 
 HBM_BYTES = 16 * 2**30
 
-# (entry point, shapes of its three operands)
+# (entry point, shapes of its three operands[, keyword arguments])
 CASES = {
     "gram_stats-higgs": ("gram_stats", [(77000, 29), (77000,), (77000,)]),
     "gram_stats_shared-higgs": (
@@ -33,6 +33,11 @@ CASES = {
         "gram_stats_multi", [(227, 785), (227, 62), (227, 62)]),
     "gram_stats_fleet-femnist": (
         "gram_stats_fleet", [(16, 256, 785), (16, 256, 62), (16, 256, 62)]),
+    # an edge aggregator's bucket folded in the kernel: one (62, 896, 896)
+    # output block for the 32 clients
+    "gram_stats_fleet_fold-femnist": (
+        "gram_stats_fleet", [(32, 512, 785), (32, 512, 62), (32, 512, 62)],
+        {"fold": True}),
 }
 
 
@@ -63,11 +68,12 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     import jax
     import jax.numpy as jnp
     kernels = importlib.import_module("repro.kernels.gram_stats")
-    name, shapes = CASES[case]
+    name, shapes, *kw = CASES[case]
     fn = getattr(kernels, name)
+    kw = kw[0] if kw else {}
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
+    compiled = jax.jit(lambda *a: fn(*a, interpret=False, **kw)).lower(
         *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
