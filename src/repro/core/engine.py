@@ -20,21 +20,28 @@ engine composes the axes instead (DESIGN.md §7):
 * **scenario**  — who participates and when (``core/scenario.py``:
   partition strategy, dropout, late-join admission, stragglers).
 
-The local transport's client phase has three gears (DESIGN.md §8):
+The local transport's client phase has three gears (DESIGN.md §8),
+selected by ``batch_clients`` and ``fused``:
 
 * the **per-client loop** (default) — one dispatch per participant,
 * ``batch_clients=True`` — participants are grouped into power-of-two
-  sample-count *buckets*, each bucket zero-padded and stacked into one
-  ``Wire.local_stats_batch`` dispatch (compile count O(log n-spread)
-  instead of O(distinct shapes)); per-client statistics still
-  materialize, so the merge/solve is byte-for-byte the loop path's — on
-  the gram wire the returned ``W`` bit-matches the loop (tested),
+  sample-count *buckets*, each one ``Wire.local_stats_batch`` dispatch
+  (compile count O(log n-spread)); per-client statistics still
+  materialize and fold as on the loop, so on the gram wire ``W``
+  bit-matches the loop (tested),
 * ``fused=True`` — per-client statistics never materialize: each bucket
-  runs a single jitted stats → leading-axis-merge program with donated
-  input buffers, and a round with one bucket and no late joiners is ONE
-  compiled program ending in the solve. Fastest, but the leading-axis
-  merge reorders float additions, so parity with the loop is to rounding
-  (not bitwise).
+  runs one jitted stats → leading-axis-merge program with donated
+  inputs, and a round with one bucket and no late joiners is ONE
+  compiled program ending in the solve. Parity with the loop is to
+  rounding (the merge reorders float additions).
+
+The gears share one path per step (DESIGN.md §7): every bucket —
+batched, fused, masked fused or a tier's edge bucket — is stacked,
+dispatched and timed by :meth:`FederationEngine._dispatch_bucket`;
+every fused bucket program comes from
+:meth:`FederationEngine._bucket_program`, keyed by fold codec
+(``float`` | ``exact`` | ``masked``); every flat round ends in
+:meth:`FederationEngine._coordinator`.
 
 Beyond the single round, :meth:`FederationEngine.run_events` drives a
 :class:`~.scenario.Timeline` of join/leave/revise events against a
@@ -70,6 +77,7 @@ returned ``W`` is the direct solve over the participating clients' union
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, List, Optional, Sequence
@@ -642,7 +650,10 @@ class FederationEngine:
         stats, time_by = phase["stats"], phase["time_by"]
         wire_bytes = sum(self._cw().wire_bytes(stats[i])
                          for i in phase["uploaders"])
-        W, W_first, coordinator_time = self._coordinator(stats, roles)
+        W, W_first, coordinator_time = self._coordinator(
+            [stats[i] for i in roles.on_time],
+            [stats[i] for i in roles.late] if roles.late else None,
+            self._fold)
         contribution = dict(phase["contribution"])
         contribution["scoring_client_s"] = float(
             sum(time_by[i] for i in phase["uploaders"]
@@ -952,22 +963,24 @@ class FederationEngine:
         return agg if self._priv is None else \
             self._priv.finalize(agg, salt=salt)
 
-    def _coordinator(self, stats, roles):
-        """Shared merge → (first solve →) solve tail, timed."""
+    def _coordinator(self, on_time, late, fold):
+        """The round's coordinator tail, timed: ``fold`` the on-time
+        uploads; when the round has late joiners (``late`` is not None,
+        even if it holds no upload), a first solve from the on-time
+        group — a usable model — then the late uploads merged in one by
+        one (paper §3.2); then the solve. Returns ``(W, W_first, s)``."""
         cw = self._cw()
         t0 = time.perf_counter()
-        with self.trace.span("merge", n_uploads=len(roles.on_time)) as sp:
-            agg = sp.ready(self._fold([stats[i] for i in roles.on_time]))
+        with self.trace.span("merge", n_uploads=len(on_time)) as sp:
+            agg = sp.ready(fold(on_time))
         W_first = None
-        if roles.late:
-            # first solve from the on-time group — a usable model — then
-            # admit the late joiners incrementally (paper §3.2)
+        if late is not None:
             with self.trace.span("solve", first=True):
                 W_first = cw.solve(self._release(agg, salt=1), self.lam)
                 jax.block_until_ready(W_first)
-            with self.trace.span("merge", n_uploads=len(roles.late)) as sp:
-                for i in roles.late:
-                    agg = cw.merge(agg, stats[i])
+            with self.trace.span("merge", n_uploads=len(late)) as sp:
+                for st in late:
+                    agg = cw.merge(agg, st)
                 sp.ready(agg)
         with self.trace.span("solve"):
             W = cw.solve(self._release(agg, salt=0), self.lam)
@@ -987,10 +1000,8 @@ class FederationEngine:
             # announces it): distributed noise shares scale to the
             # participants that will actually sum, not the universe
             self._priv.cohort = len(roles.participants)
-        if self.batch_clients and self.transport == "local":
-            if self.fused:
-                return self._run_fused(parts_X, parts_d, roles)
-            return self._run_batched(parts_X, parts_d, roles)
+        if self.fused and self.transport == "local":
+            return self._run_fused(parts_X, parts_d, roles)
         stats, time_by, dispatches = self._phase_stats(
             parts_X, parts_d, roles.participants)
         if self.warmup and roles.participants and \
@@ -1004,7 +1015,10 @@ class FederationEngine:
                 self.wire.merge(stats[i0], stats[i0]), self.lam))
         wire_bytes = sum(self._cw().wire_bytes(stats[i])
                          for i in roles.participants)
-        W, W_first, coordinator_time = self._coordinator(stats, roles)
+        W, W_first, coordinator_time = self._coordinator(
+            [stats[i] for i in roles.on_time],
+            [stats[i] for i in roles.late] if roles.late else None,
+            self._fold)
         return RoundReport(
             W=W, client_times=[time_by[i] for i in roles.participants],
             coordinator_time=coordinator_time,
@@ -1012,8 +1026,8 @@ class FederationEngine:
             n_samples=sum(int(parts_X[i].shape[0])
                           for i in roles.participants),
             W_first=W_first, dispatches=dispatches,
-            # the flat coordinator materializes every upload before the
-            # fold — residency IS the round's wire bytes, O(P)
+            # the loop and batched gears materialize every upload before
+            # the fold — residency IS the round's wire bytes, O(P)
             peak_coordinator_bytes=wire_bytes)
 
     # -------------------------------------------- fleet-batched client phase
@@ -1031,8 +1045,9 @@ class FederationEngine:
                                []).append(i)
         return sorted(buckets.items())
 
-    def _stack_bucket(self, parts_X, parts_d, idxs, bound):
-        """Stack a bucket's shards into zero-padded (P_b, bound, ·) arrays.
+    def _pad_stack(self, parts_X, parts_d, slots, lead, bound):
+        """Zero-padded ``(*lead, bound, ·)`` host stacks holding shard
+        ``i`` at position ``pos`` for each ``(pos, i)`` of ``slots``.
 
         Pad rows are all-zero in X (the wire supplies the bias column as
         the validity mask) and carry the activation midpoint ``f(0)`` in
@@ -1040,20 +1055,27 @@ class FederationEngine:
         padding convention (:func:`pad_for_mesh`).
         """
         np_dtype = np.dtype(getattr(self.wire, "dtype", np.float32))
-        m_in = parts_X[idxs[0]].shape[1]
-        c = parts_d[idxs[0]].shape[1]
+        i0 = slots[0][1]
+        m_in, c = parts_X[i0].shape[1], parts_d[i0].shape[1]
+        mid = float(acts.get(self.wire.act).f(jnp.zeros((), jnp.float32)))
+        Xs = np.zeros((*lead, bound, m_in), np_dtype)
+        Ds = np.full((*lead, bound, c), mid, np_dtype)
+        ns = np.zeros(lead, np.int32)
+        for pos, i in slots:
+            n = int(parts_X[i].shape[0])
+            Xs[pos][:n] = np.asarray(parts_X[i], np_dtype)
+            Ds[pos][:n] = np.asarray(parts_d[i], np_dtype)
+            ns[pos] = n
+        return Xs, Ds, ns
+
+    def _stack_bucket(self, parts_X, parts_d, idxs, bound):
+        """Stack a bucket's shards into zero-padded (P_b, bound, ·)
+        arrays (:meth:`_pad_stack`)."""
         with self.trace.span("bucket.stack", bound=int(bound),
                              n_clients=len(idxs)) as sp:
-            mid = float(acts.get(self.wire.act).f(
-                jnp.zeros((), jnp.float32)))
-            Xs = np.zeros((len(idxs), bound, m_in), np_dtype)
-            Ds = np.full((len(idxs), bound, c), mid, np_dtype)
-            ns = np.zeros((len(idxs),), np.int32)
-            for row, i in enumerate(idxs):
-                n = int(parts_X[i].shape[0])
-                Xs[row, :n] = np.asarray(parts_X[i], np_dtype)
-                Ds[row, :n] = np.asarray(parts_d[i], np_dtype)
-                ns[row] = n
+            Xs, Ds, ns = self._pad_stack(parts_X, parts_d,
+                                         list(enumerate(idxs)),
+                                         (len(idxs),), bound)
             if self.trace.enabled:
                 # pulled: the device-resident shards read back; built:
                 # the stacks the next dispatch uploads
@@ -1082,6 +1104,69 @@ class FederationEngine:
             time_by[i] = time_by.get(i, 0.0) + \
                 dt * (int(n) / total if total else 1 / len(idxs))
 
+    def _clip(self, parts_X, idxs, time_by):
+        """DP per-row clipping of ``idxs``' shards (a copy of
+        ``parts_X``). Clipping is client-side work: it runs inside the
+        metered region and charges each client's clock (the §4.1
+        metrics include it); the client passes then read the clipped
+        shards."""
+        if self._priv is None or not self._priv.policy.dp:
+            return parts_X
+        parts_X = dict(parts_X) if isinstance(parts_X, dict) \
+            else list(parts_X)
+        for i in idxs:
+            t0 = time.perf_counter()
+            parts_X[i] = self._priv.clip(parts_X[i])
+            time_by[i] = time_by.get(i, 0.0) + \
+                (time.perf_counter() - t0)
+        return parts_X
+
+    def _client_pass(self, i, parts_X, parts_d, tally, *,
+                     span="client.stats", encode=None, **attrs):
+        """One client's own statistics pass (:meth:`_client_stats`: the
+        loop's, the stream transport's chunk fold, an empty shard's
+        exactly-zero statistics), then the codec's ``encode(i, stats)``
+        if any: timed into the client's clock and counted as one
+        dispatch."""
+        t0 = time.perf_counter()
+        with self.trace.span(span, track="client", cid=int(i), **attrs):
+            st = self._client_stats(parts_X[i], parts_d[i])
+            with self.trace.span("client.wait", track="client"):
+                jax.block_until_ready(st)
+            if encode is not None:
+                st = encode(int(i), st)
+        tally.time_by[i] = tally.time_by.get(i, 0.0) + \
+            (time.perf_counter() - t0)
+        tally.dispatches += 1
+        return st
+
+    def _dispatch_bucket(self, fn, parts_X, parts_d, idxs, bound, tally,
+                         *, extra=(), x64=False, warm=False, n_calls=1,
+                         **span_attrs):
+        """One pow2 bucket of shards as one device program
+        ``fn(Xs, Ds, ns, *extra)``: stack it, warm up (``warm``) on a
+        fresh stack — the program may donate its inputs — then dispatch
+        under ``bucket.dispatch`` and wait under its ``client.wait``,
+        count ``n_calls`` dispatches and charge the wall time to the
+        bucket's clients by sample share. It blocks before returning, so
+        the next bucket's stack is built after this program ends.
+        Returns the program's raw output (the caller decodes it)."""
+        Xs, Ds, ns = self._stack_bucket(parts_X, parts_d, idxs, bound)
+        with enable_x64() if x64 else contextlib.nullcontext():
+            if warm:
+                jax.block_until_ready(fn(*self._stack_bucket(
+                    parts_X, parts_d, idxs, bound), *extra))
+            t0 = time.perf_counter()
+            with self.trace.span("bucket.dispatch", bound=int(bound),
+                                 n_clients=len(idxs), **span_attrs):
+                out = fn(Xs, Ds, ns, *extra)
+                with self.trace.span("client.wait"):
+                    jax.block_until_ready(out)
+        tally.dispatches += n_calls
+        self._share_times(tally.time_by, idxs, ns,
+                          time.perf_counter() - t0)
+        return out
+
     def _phase_stats(self, parts_X, parts_d, idxs):
         """Client-phase statistics for ``idxs`` — one dispatch per shape
         bucket when ``batch_clients`` (local transport only: streaming
@@ -1089,18 +1174,8 @@ class FederationEngine:
         loop. Returns ``(stats, time_by, dispatches)`` keyed by client
         index.
         """
-        stats, time_by, dispatches = {}, {}, 0
-        if self._priv is not None and self._priv.policy.dp:
-            # per-row clipping is client-side work: run it inside the
-            # metered region and charge each client's clock for it
-            # (the module docstring and privacy_bench both promise the
-            # §4.1 metrics include it)
-            clipped = {}
-            for i in idxs:
-                t0 = time.perf_counter()
-                clipped[i] = self._priv.clip(parts_X[i])
-                time_by[i] = time.perf_counter() - t0
-            parts_X = clipped
+        stats, tally = {}, _Tally()
+        parts_X = self._clip(parts_X, idxs, tally.time_by)
         if not (self.batch_clients and self.transport == "local"):
             if self.warmup and idxs:
                 # untimed compile pass at the first client's shapes, as
@@ -1109,149 +1184,98 @@ class FederationEngine:
                 i0 = idxs[0]
                 jax.block_until_ready(
                     self._client_stats(parts_X[i0], parts_d[i0]))
-            for i in idxs:
-                t0 = time.perf_counter()
-                with self.trace.span("client.stats", track="client",
-                                     cid=int(i)):
-                    stats[i] = self._client_stats(parts_X[i],
-                                                  parts_d[i])
-                    with self.trace.span("client.wait", track="client"):
-                        jax.block_until_ready(stats[i])
-                time_by[i] = time_by.get(i, 0.0) + \
-                    (time.perf_counter() - t0)
-                dispatches += 1
-            return self._encode_stats(stats, time_by), time_by, \
-                dispatches
-        for bound, b_idxs in self._buckets(parts_X, idxs):
+            buckets = [(0, idxs)]
+        else:
+            buckets = self._buckets(parts_X, idxs)
+        # a wire riding _WireBase's default batch (a per-client loop
+        # over the stack) really dispatches once per client — keep the
+        # dispatch metric honest for custom wires
+        native = type(self.wire).local_stats_batch \
+            is not _WireBase.local_stats_batch
+        for bound, b_idxs in buckets:
             if bound == 0:
-                # empty shards: per-client call (their statistics are
-                # exactly zero but still count one upload, as on the loop)
+                # the loop, and empty shards on the batched gear (their
+                # statistics are exactly zero but still count one
+                # upload, as on the loop)
                 for i in b_idxs:
-                    t0 = time.perf_counter()
-                    with self.trace.span("client.stats",
-                                         track="client", cid=int(i)):
-                        stats[i] = self.wire.local_stats(parts_X[i],
-                                                         parts_d[i])
-                        with self.trace.span("client.wait",
-                                             track="client"):
-                            jax.block_until_ready(stats[i])
-                    time_by[i] = time_by.get(i, 0.0) + \
-                        (time.perf_counter() - t0)
-                    dispatches += 1
+                    stats[i] = self._client_pass(i, parts_X, parts_d,
+                                                 tally)
                 continue
-            Xs, Ds, ns = self._stack_bucket(parts_X, parts_d, b_idxs,
-                                            bound)
-            if self.warmup:
-                # compile this bucket's stacked shape once, untimed
-                jax.block_until_ready(
-                    self.wire.local_stats_batch(Xs, Ds, ns))
-            t0 = time.perf_counter()
-            with self.trace.span("bucket.dispatch", bound=int(bound),
-                                 n_clients=len(b_idxs)):
-                batch = self.wire.local_stats_batch(Xs, Ds, ns)
-                with self.trace.span("client.wait"):
-                    jax.block_until_ready(batch)
-            # a wire riding _WireBase's default batch (a per-client loop
-            # over the stack) really dispatches once per client — keep
-            # the dispatch metric honest for custom wires
-            native = type(self.wire).local_stats_batch \
-                is not _WireBase.local_stats_batch
-            dispatches += 1 if native else len(b_idxs)
-            self._share_times(time_by, b_idxs, ns,
-                              time.perf_counter() - t0)
+            batch = self._dispatch_bucket(
+                self.wire.local_stats_batch, parts_X, parts_d, b_idxs,
+                bound, tally, warm=self.warmup,
+                n_calls=1 if native else len(b_idxs))
             stats.update(zip(b_idxs, batch))
-        return self._encode_stats(stats, time_by), time_by, dispatches
-
-    def _run_batched(self, parts_X, parts_d, roles) -> RoundReport:
-        stats, time_by, dispatches = self._phase_stats(
-            parts_X, parts_d, roles.participants)
-        if self.warmup and roles.participants and \
-                not (self._priv is not None and self._priv.masked):
-            i0 = roles.participants[0]
-            jax.block_until_ready(self.wire.solve(
-                self.wire.merge(stats[i0], stats[i0]), self.lam))
-        wire_bytes = sum(self._cw().wire_bytes(stats[i])
-                         for i in roles.participants)
-        W, W_first, coordinator_time = self._coordinator(stats, roles)
-        return RoundReport(
-            W=W, client_times=[time_by[i] for i in roles.participants],
-            coordinator_time=coordinator_time, wire_bytes=wire_bytes,
-            roles=roles,
-            n_samples=sum(int(parts_X[i].shape[0])
-                          for i in roles.participants),
-            W_first=W_first, dispatches=dispatches,
-            # per-client statistics materialize before the fold, as on
-            # the loop path: residency = the round's upload bytes
-            peak_coordinator_bytes=wire_bytes)
+        return self._encode_stats(stats, tally.time_by), tally.time_by, \
+            tally.dispatches
 
     # ------------------------------------------------------ fused round
-    def _fused_fn(self, with_solve: bool):
-        """stats → leading-axis merge (→ solve) as ONE jitted program.
+    def _bucket_body(self, codec: str, solve: bool = False,
+                     share: float = 0.0, words: int = 0):
+        """One bucket's fused program body, by fold codec (the names
+        :meth:`_hier_mode` gives):
 
-        The merge is the wire's folded fleet pass (``fleet_stats(...,
-        fold=True)``): on the gram wire's Pallas kernel the bucket's
-        clients accumulate in place, so the program never holds the
-        per-client statistics stack. The stacked client buffers are
-        donated (no-op on CPU, where XLA does not implement donation) —
-        at P=1000 the (P, n_max, m) stack is the round's dominant
-        allocation and the program may reuse it in place.
+        * ``float`` — the wire's folded fleet pass (``fleet_stats(...,
+          fold=True)``: on the gram wire's Pallas kernel the bucket's
+          clients accumulate in place, so the program never holds the
+          per-client statistics stack), then the solve when ``solve``;
+        * ``exact`` — per-client fleet stats → exact dyadic limb encode
+          (``words`` limbs) → ring sum over the client axis →
+          carry-normalize: the bucket's ring aggregate, whose integer
+          adds are order-independent, so any re-tiering decodes to the
+          bit-identical flat exact fold;
+        * ``masked`` — ``exact`` with per-client σ/√cohort noise shares
+          (secagg+dp, ``share``) before the encode and the clients'
+          pairwise pads (a lazy ring add) before the sum. Per-client
+          statistics exist only as traced intermediates; the host wraps
+          the masked ring aggregate via ``SecAggSession.from_flat``.
+
+        The limb codecs run under x64 (int64 limbs); the f32 statistics
+        are unchanged by it — JAX's weak typing keeps explicitly-dtyped
+        programs bit-stable (pinned by the conformance suite).
         """
-        if with_solve not in self._fused_cache:
-            wire, lam = self.wire, self.lam
-
+        wire, lam = self.wire, self.lam
+        if codec == "float":
             def prog(Xs, Ds, ns):
                 agg = wire.fleet_stats(Xs, Ds, ns, fold=True)
-                return wire.solve(agg, lam) if with_solve else agg
+                return wire.solve(agg, lam) if solve else agg
+            return prog
+        from ..privacy import limbs as _limbs
+        priv = self._priv
+        noisy = codec == "masked" and priv.policy.dp
 
-            donate = (0, 1) if jax.default_backend() != "cpu" else ()
-            self._fused_cache[with_solve] = jax.jit(
-                prog, donate_argnums=donate)
-        return self._fused_cache[with_solve]
+        def prog(Xs, Ds, ns, *pads_keys):
+            st = wire.fleet_stats(Xs, Ds, ns)
+            if noisy:
+                st = priv.noise_shares_stacked(st, pads_keys[1], share)
+            enc = _limbs.encode_tree(wire.secagg_encode(st), words,
+                                     stacked=True)
+            if pads_keys:
+                enc = _limbs.add_limbs(enc, pads_keys[0])
+            return _limbs.carry_limbs(_limbs.sum_limbs(enc))
+        return prog
 
-    def _masked_fused_fn(self, share: float):
-        """One bucket's masked round as ONE jitted program: fleet stats
-        → (per-client σ/√cohort noise shares, secagg+dp) → exact limb
-        encode → pairwise pads (lazy ring add) → ring sum over the
-        client axis → carry-normalize. Per-client statistics exist only
-        as traced intermediates; the program's sole output is the
-        bucket's masked ring aggregate, which the host wraps via
-        ``SecAggSession.from_flat``. Runs under x64 (the limb ops are
-        int64); the f32 statistics themselves are unchanged by x64 —
-        JAX's weak typing keeps explicitly-dtyped programs bit-stable
-        (pinned by the conformance suite).
+    def _bucket_program(self, codec: str, *, solve: bool = False,
+                        share: float = 0.0, words: int = 0):
+        """:meth:`_bucket_body` as one jitted program, cached per engine.
+
+        The stacked client buffers are donated (no-op on CPU, where XLA
+        does not implement donation) — at P=1000 the (P, n_max, m) stack
+        is the round's dominant allocation and the program may reuse it
+        in place.
         """
-        key = ("masked", share)
+        key = (codec, solve, share, words)
         if key not in self._fused_cache:
-            from ..privacy import limbs as _limbs
-            wire, priv = self.wire, self._priv
-            words = priv.session.words
-            noisy = priv.policy.dp
-
-            def prog(Xs, Ds, ns, pads, keys):
-                st = wire.fleet_stats(Xs, Ds, ns)
-                if noisy:
-                    st = priv.noise_shares_stacked(st, keys, share)
-                enc = _limbs.encode_tree(wire.secagg_encode(st), words,
-                                         stacked=True)
-                return _limbs.carry_limbs(
-                    _limbs.sum_limbs(_limbs.add_limbs(enc, pads)))
-
             donate = (0, 1) if jax.default_backend() != "cpu" else ()
-            self._fused_cache[key] = jax.jit(prog, donate_argnums=donate)
+            self._fused_cache[key] = jax.jit(
+                self._bucket_body(codec, solve, share, words),
+                donate_argnums=donate)
         return self._fused_cache[key]
 
     def _run_fused(self, parts_X, parts_d, roles) -> RoundReport:
         priv = self._priv
-        time_by = {i: 0.0 for i in roles.participants}
-        if priv is not None and priv.policy.dp:
-            # per-row clipping is client-side work, timed per client as
-            # on the loop path; the fused programs then consume the
-            # clipped shards
-            parts_X = list(parts_X)
-            for i in roles.participants:
-                t0 = time.perf_counter()
-                parts_X[i] = priv.clip(parts_X[i])
-                time_by[i] = time.perf_counter() - t0
+        tally = _Tally({i: 0.0 for i in roles.participants})
+        parts_X = self._clip(parts_X, roles.participants, tally.time_by)
         on_buckets = [b for b in self._buckets(parts_X, roles.on_time)
                       if b[0] > 0]
         late_buckets = [b for b in self._buckets(parts_X, roles.late)
@@ -1259,38 +1283,18 @@ class FederationEngine:
         # empty shards contribute exactly-zero statistics: they never
         # enter a fused program, only the (analytic) upload accounting —
         # except under masking, where even a zero upload carries pads
-        # that must cancel in the aggregate (handled below)
+        # that must cancel in the aggregate (:meth:`_fused_masked`)
         m_in = parts_X[0].shape[1] if len(parts_X) else 0
         c = parts_d[0].shape[1] if len(parts_d) else 1
         wire_bytes = sum(
             self._cw().stats_bytes(int(parts_X[i].shape[0]), m_in, c)
             for i in roles.participants)
-        dispatches = 0
 
         def run_bucket(fn, idxs, bound):
-            nonlocal dispatches
-            Xs, Ds, ns = self._stack_bucket(parts_X, parts_d, idxs, bound)
-            if self.warmup:
-                jax.block_until_ready(
-                    fn(*self._stack_bucket(parts_X, parts_d, idxs,
-                                           bound)))
-            t0 = time.perf_counter()
-            with self.trace.span("bucket.dispatch", bound=int(bound),
-                                 n_clients=len(idxs), fused=True,
-                                 **self._out_attrs(len(idxs), bound, m_in,
-                                                   c, fold=True)):
-                out = fn(Xs, Ds, ns)
-                with self.trace.span("client.wait"):
-                    jax.block_until_ready(out)
-            dispatches += 1
-            self._share_times(time_by, idxs, ns,
-                              time.perf_counter() - t0)
-            return out
-
-        if priv is not None and priv.masked:
-            return self._run_fused_masked(
-                parts_X, parts_d, roles, on_buckets, late_buckets,
-                time_by, wire_bytes)
+            return self._dispatch_bucket(
+                fn, parts_X, parts_d, idxs, bound, tally,
+                warm=self.warmup, fused=True,
+                **self._out_attrs(len(idxs), bound, m_in, c, fold=True))
 
         # a scenario with late joiners must produce W_first even if every
         # late shard is empty (late_buckets drops bound-0 shards), so the
@@ -1299,16 +1303,20 @@ class FederationEngine:
         # the solve cannot fuse into the program
         one_shot = len(on_buckets) == 1 and not roles.late \
             and priv is None
-        if one_shot:
+        if priv is not None and priv.masked:
+            W, W_first, coordinator_time, peak = self._fused_masked(
+                parts_X, parts_d, roles, on_buckets, late_buckets, tally)
+        elif one_shot:
             # the whole round — every client's pass, the merge, and the
             # solve — is one compiled dispatch
             bound, idxs = on_buckets[0]
-            W = run_bucket(self._fused_fn(True), idxs, bound)
+            W = run_bucket(self._bucket_program("float", solve=True),
+                           idxs, bound)
             W_first, coordinator_time = None, 0.0
             peak = 0    # per-client stats and the aggregate live only
             #             as traced intermediates of the one dispatch
         else:
-            partial = self._fused_fn(False)
+            partial = self._bucket_program("float")
             on_aggs = [run_bucket(partial, idxs, bound)
                        for bound, idxs in on_buckets]
             late_aggs = [run_bucket(partial, idxs, bound)
@@ -1316,48 +1324,34 @@ class FederationEngine:
             # every bucket aggregate is host-resident before the fold
             peak = sum(self.wire.wire_bytes(a)
                        for a in on_aggs + late_aggs)
-            t0 = time.perf_counter()
-            with self.trace.span("merge", n_uploads=len(on_aggs)) as sp:
-                agg = self.wire.merge_many(on_aggs) if on_aggs else None
-                W_first = None
-                if agg is None:
-                    # every on-time shard was empty: fall back to their
-                    # (zero) per-client statistics so the solve still
-                    # runs
-                    agg = self._fold([self.wire.local_stats(parts_X[i],
-                                                            parts_d[i])
-                                      for i in roles.on_time])
-                sp.ready(agg)
-            if roles.late:
-                with self.trace.span("solve", first=True):
-                    W_first = self.wire.solve(
-                        self._release(agg, salt=1), self.lam)
-                    jax.block_until_ready(W_first)
-                with self.trace.span("merge",
-                                     n_uploads=len(late_aggs)) as sp:
-                    for st in late_aggs:
-                        agg = self.wire.merge(agg, st)
-                    sp.ready(agg)
-            with self.trace.span("solve"):
-                W = self.wire.solve(self._release(agg, salt=0),
-                                    self.lam)
-                jax.block_until_ready(W)
-            coordinator_time = time.perf_counter() - t0
+
+            def fold(aggs):
+                if aggs:
+                    return self.wire.merge_many(aggs)
+                # every on-time shard was empty: fall back to their
+                # (zero) per-client statistics so the solve still runs
+                return self._fold([self.wire.local_stats(parts_X[i],
+                                                         parts_d[i])
+                                   for i in roles.on_time])
+
+            W, W_first, coordinator_time = self._coordinator(
+                on_aggs, late_aggs if roles.late else None, fold)
         return RoundReport(
-            W=W, client_times=[time_by[i] for i in roles.participants],
+            W=W, client_times=[tally.time_by[i]
+                               for i in roles.participants],
             coordinator_time=coordinator_time, wire_bytes=wire_bytes,
             roles=roles,
             n_samples=sum(int(parts_X[i].shape[0])
                           for i in roles.participants),
-            W_first=W_first, dispatches=dispatches,
+            W_first=W_first, dispatches=tally.dispatches,
             peak_coordinator_bytes=peak)
 
-    def _run_fused_masked(self, parts_X, parts_d, roles, on_buckets,
-                          late_buckets, time_by, wire_bytes
-                          ) -> RoundReport:
+    def _fused_masked(self, parts_X, parts_d, roles, on_buckets,
+                      late_buckets, tally):
         """The fused round under masking: one jitted masked program per
-        bucket (``_masked_fused_fn``), the ordinary MaskedWire
-        merge/solve tail on the per-bucket ring aggregates. A uniform
+        bucket (``_bucket_program("masked")``), the ordinary MaskedWire
+        merge/solve tail on the per-bucket ring aggregates. Returns
+        ``(W, W_first, coordinator_time, peak)``. A uniform
         masked round (one bucket, no late joiners, no empty shards) is
         ONE client-phase dispatch, exactly like the unprivate fused
         path — ring addition is order-independent, so ``W`` bit-matches
@@ -1375,35 +1369,17 @@ class FederationEngine:
         from ..privacy.limbs import check_fleet_headroom
         check_fleet_headroom(len(roles.participants))
         share = priv.share_sigma(template) if priv.policy.dp else 0.0
-        fn = self._masked_fused_fn(share)
-        dispatches = 0
+        fn = self._bucket_program("masked", share=share, words=sess.words)
 
-        def run_masked_bucket(idxs, bound):
-            nonlocal dispatches
-            Xs, Ds, ns = self._stack_bucket(parts_X, parts_d, idxs,
-                                            bound)
-            pads = sess.flat_pad_sums(idxs)
+        def run_bucket(idxs, bound):
+            # warm-up reuses the same keys — its output is discarded,
+            # never released
             keys = priv.share_keys(idxs) if priv.policy.dp else \
                 np.zeros((len(idxs), 2), np.uint32)
-            with enable_x64():
-                if self.warmup:
-                    # fresh stack: the program may have donated buffers
-                    # (warmup reuses the same keys — its output is
-                    # discarded, never released)
-                    jax.block_until_ready(fn(
-                        *self._stack_bucket(parts_X, parts_d, idxs,
-                                            bound), pads, keys))
-                t0 = time.perf_counter()
-                with self.trace.span("bucket.dispatch",
-                                     bound=int(bound),
-                                     n_clients=len(idxs), fused=True,
-                                     masked=True):
-                    out = fn(Xs, Ds, ns, pads, keys)
-                    with self.trace.span("client.wait"):
-                        jax.block_until_ready(out)
-            dispatches += 1
-            self._share_times(time_by, idxs, ns,
-                              time.perf_counter() - t0)
+            out = self._dispatch_bucket(
+                fn, parts_X, parts_d, idxs, bound, tally,
+                extra=(sess.flat_pad_sums(idxs), keys), x64=True,
+                warm=self.warmup, fused=True, masked=True)
             return sess.from_flat(np.asarray(out),
                                   frozenset(int(i) for i in idxs))
 
@@ -1411,56 +1387,23 @@ class FederationEngine:
             # empty shards still publish: their zero statistics carry
             # pads (and noise shares) the aggregate needs to cancel —
             # a real per-client dispatch, timed and counted
-            nonlocal dispatches
-            out = []
-            for i in idxs:
-                t0 = time.perf_counter()
-                with self.trace.span("mask.encode", track="client",
-                                     cid=int(i), empty=True):
-                    st = self.wire.local_stats(parts_X[i], parts_d[i])
-                    out.append(priv.client_encode(int(i), st))
-                time_by[i] = time_by.get(i, 0.0) + \
-                    (time.perf_counter() - t0)
-                dispatches += 1
-            return out
+            return [self._client_pass(i, parts_X, parts_d, tally,
+                                      span="mask.encode",
+                                      encode=priv.client_encode,
+                                      empty=True)
+                    for i in idxs if int(parts_X[i].shape[0]) == 0]
 
-        on_aggs = [run_masked_bucket(idxs, bound)
-                   for bound, idxs in on_buckets]
-        on_aggs += mask_empties(
-            [i for i in roles.on_time
-             if int(parts_X[i].shape[0]) == 0])
-        late_aggs = [run_masked_bucket(idxs, bound)
+        on_aggs = [run_bucket(idxs, bound) for bound, idxs in on_buckets]
+        on_aggs += mask_empties(roles.on_time)
+        late_aggs = [run_bucket(idxs, bound)
                      for bound, idxs in late_buckets]
-        late_aggs += mask_empties(
-            [i for i in roles.late if int(parts_X[i].shape[0]) == 0])
+        late_aggs += mask_empties(roles.late)
         # every masked bucket/empty-shard aggregate (a fixed-size ring
         # element) is host-resident before the fold
         peak = (len(on_aggs) + len(late_aggs)) * sess.upload_bytes
-        t0 = time.perf_counter()
-        with self.trace.span("merge", n_uploads=len(on_aggs)) as sp:
-            agg = sp.ready(cw.merge_many(on_aggs))
-        W_first = None
-        if roles.late:
-            with self.trace.span("solve", first=True):
-                W_first = cw.solve(self._release(agg, salt=1),
-                                   self.lam)
-                jax.block_until_ready(W_first)
-            with self.trace.span("merge", n_uploads=len(late_aggs)) as sp:
-                for st in late_aggs:
-                    agg = cw.merge(agg, st)
-                sp.ready(agg)
-        with self.trace.span("solve"):
-            W = cw.solve(self._release(agg, salt=0), self.lam)
-            jax.block_until_ready(W)
-        coordinator_time = time.perf_counter() - t0
-        return RoundReport(
-            W=W, client_times=[time_by[i] for i in roles.participants],
-            coordinator_time=coordinator_time, wire_bytes=wire_bytes,
-            roles=roles,
-            n_samples=sum(int(parts_X[i].shape[0])
-                          for i in roles.participants),
-            W_first=W_first, dispatches=dispatches,
-            peak_coordinator_bytes=peak)
+        return (*self._coordinator(on_aggs,
+                                   late_aggs if roles.late else None,
+                                   cw.merge_many), peak)
 
     # ------------------------------------------------ hierarchical round
     def _hier_mode(self) -> str:
@@ -1487,31 +1430,6 @@ class FederationEngine:
                 "exact=off for the float fold")
         return "exact" if capable else "float"
 
-    def _exact_fused_fn(self, words: int):
-        """One edge bucket's exact group fold as ONE jitted program:
-        fleet stats → exact dyadic limb encode → ring sum over the
-        member axis → carry-normalize. The unmasked twin of
-        ``_masked_fused_fn`` (no pads, no noise shares): its output is
-        the group's ring aggregate — the unit tiers exchange, whose
-        integer adds are order-independent, so any re-tiering decodes
-        to the bit-identical flat exact fold. Runs under x64 (int64
-        limbs); the f32 statistics are unchanged by it (weak typing,
-        pinned by the conformance suite)."""
-        key = ("exact", words)
-        if key not in self._fused_cache:
-            from ..privacy import limbs as _limbs
-            wire = self.wire
-
-            def prog(Xs, Ds, ns):
-                st = wire.fleet_stats(Xs, Ds, ns)
-                enc = _limbs.encode_tree(wire.secagg_encode(st), words,
-                                         stacked=True)
-                return _limbs.carry_limbs(_limbs.sum_limbs(enc))
-
-            donate = (0, 1) if jax.default_backend() != "cpu" else ()
-            self._fused_cache[key] = jax.jit(prog, donate_argnums=donate)
-        return self._fused_cache[key]
-
     def _hier_mesh_groups(self, parts_X, parts_d, tree, subset, mode,
                           words, time_by, warmed):
         """ALL of ``subset``'s edge groups as ONE sharded dispatch:
@@ -1525,9 +1443,7 @@ class FederationEngine:
         aggregate at once — peak residency is n_groups·agg_bytes, the
         devices-for-memory trade the mesh makes (the bench's flat-in-P
         row therefore runs the local transport)."""
-        import contextlib
         from jax.sharding import PartitionSpec as P
-        wire = self.wire
         mesh = self.mesh or make_client_mesh(axis=self.axis)
         Dn = mesh.shape[self.axis]
         groups = []
@@ -1542,51 +1458,21 @@ class FederationEngine:
         bound = max(_bucket_bound(int(parts_X[i].shape[0]))
                     for _, m in groups for i in m)
         G = -(-len(groups) // Dn) * Dn
-        np_dtype = np.dtype(getattr(wire, "dtype", np.float32))
-        i00 = groups[0][1][0]
-        m_in, c = parts_X[i00].shape[1], parts_d[i00].shape[1]
-        mid = float(acts.get(wire.act).f(jnp.zeros((), jnp.float32)))
-        Xs = np.zeros((G, gsize, bound, m_in), np_dtype)
-        Ds = np.full((G, gsize, bound, c), mid, np_dtype)
-        ns = np.zeros((G, gsize), np.int32)
-        for g, (_, members) in enumerate(groups):
-            for row, i in enumerate(members):
-                n = int(parts_X[i].shape[0])
-                Xs[g, row, :n] = np.asarray(parts_X[i], np_dtype)
-                Ds[g, row, :n] = np.asarray(parts_d[i], np_dtype)
-                ns[g, row] = n
-
-        if mode == "exact":
-            from ..privacy import limbs as _limbs
-
-            def group_prog(Xg, Dg, ng):
-                st = wire.fleet_stats(Xg, Dg, ng)
-                enc = _limbs.encode_tree(wire.secagg_encode(st), words,
-                                         stacked=True)
-                return _limbs.carry_limbs(_limbs.sum_limbs(enc))
-
-            out_specs = P(self.axis, None, None)
-            ctx = enable_x64()
-        else:
-            def group_prog(Xg, Dg, ng):
-                return wire.fleet_stats(Xg, Dg, ng, fold=True)
-
-            template = jax.eval_shape(
-                jax.vmap(group_prog),
-                jax.ShapeDtypeStruct(Xs.shape, Xs.dtype),
-                jax.ShapeDtypeStruct(Ds.shape, Ds.dtype),
-                jax.ShapeDtypeStruct(ns.shape, ns.dtype))
+        Xs, Ds, ns = self._pad_stack(
+            parts_X, parts_d,
+            [((g, row), i) for g, (_, members) in enumerate(groups)
+             for row, i in enumerate(members)], (G, gsize), bound)
+        vprog = jax.vmap(self._bucket_body(mode, words=words))
+        with enable_x64() if mode == "exact" else contextlib.nullcontext():
             out_specs = jax.tree_util.tree_map(
-                lambda s: P(self.axis, *([None] * (len(s.shape) - 1))),
-                template)
-            ctx = contextlib.nullcontext()
-        fn = jax.shard_map(
-            jax.vmap(group_prog), mesh=mesh,
-            in_specs=(P(self.axis, None, None, None),
-                      P(self.axis, None, None, None),
-                      P(self.axis, None)),
-            out_specs=out_specs, check_vma=False)
-        with ctx:
+                lambda s: P(self.axis, *([None] * (s.ndim - 1))),
+                jax.eval_shape(vprog, Xs, Ds, ns))
+            fn = jax.shard_map(
+                vprog, mesh=mesh,
+                in_specs=(P(self.axis, None, None, None),
+                          P(self.axis, None, None, None),
+                          P(self.axis, None)),
+                out_specs=out_specs, check_vma=False)
             wk = ("hier-mesh", mode, G, gsize, bound)
             if self.warmup and wk not in warmed:
                 warmed.add(wk)
@@ -1632,7 +1518,6 @@ class FederationEngine:
         ``report.hierarchy``; ``wire_bytes`` counts the tiered plan
         (client uploads + one uplink per non-root aggregator).
         """
-        import contextlib
         topo = self.topology
         P = len(parts_X)
         roles = self.scenario.roles(P)
@@ -1674,19 +1559,14 @@ class FederationEngine:
                     "(gram wire, exact or masked fold): float "
                     "aggregates have no bit-stable digits to commit")
             journal = RoundJournal(self.journal_path, mode=mode)
-        time_by = {i: 0.0 for i in roles.participants}
-        if priv is not None and priv.policy.dp:
-            # per-row clipping is client-side work, timed per client
-            parts_X = list(parts_X)
-            for i in roles.participants:
-                t0 = time.perf_counter()
-                parts_X[i] = priv.clip(parts_X[i])
-                time_by[i] = time.perf_counter() - t0
+        tally = _Tally({i: 0.0 for i in roles.participants})
+        time_by = tally.time_by
+        parts_X = self._clip(parts_X, roles.participants, time_by)
         i0 = roles.participants[0] if roles.participants else 0
         m_in = parts_X[i0].shape[1] if P else 0
         c = parts_d[i0].shape[1] if P else 1
-        folder = sess = None
-        share = 0.0
+        folder = sess = encode = None
+        share, words = 0.0, 0
         cw = self._cw()
         if mode != "float":
             # the codecs size their rings from one (empty) client's
@@ -1695,7 +1575,10 @@ class FederationEngine:
                 np.asarray(parts_X[i0])[:0], np.asarray(parts_d[i0])[:0])
         if mode == "exact":
             folder = ExactFold(self.wire, template)
-            agg_bytes = folder.agg_bytes
+            agg_bytes, words = folder.agg_bytes, folder.words
+
+            def encode(i, st):
+                return folder.encode(st)
         elif mode == "masked":
             priv.prepare(template)
             sess = priv.session
@@ -1704,29 +1587,20 @@ class FederationEngine:
             # the lazy-carry headroom); host merges carry-normalize
             check_fleet_headroom(tree.max_group)
             share = priv.share_sigma(template) if priv.policy.dp else 0.0
-            agg_bytes = sess.upload_bytes
+            agg_bytes, words = sess.upload_bytes, sess.words
+            encode = priv.client_encode
         else:
             # one AGGREGATE's wire size (svd factor rank caps at m)
             agg_bytes = self.wire.stats_bytes(m_in + 1, m_in, c)
         meter = _PeakMeter()
-        dispatches = 0
         merge_s = 0.0
         merges = 0
         warmed = set()
 
         def size_of(a):
-            if mode == "exact":
-                return folder.agg_bytes
-            if mode == "masked":
-                return sess.upload_bytes
-            return self.wire.wire_bytes(a)
+            return self.wire.wire_bytes(a) if mode == "float" else agg_bytes
 
-        def tier_add(a, b):
-            if mode == "exact":
-                return folder.add(a, b)
-            if mode == "masked":
-                return cw.merge(a, b)
-            return self.wire.merge(a, b)
+        tier_add = folder.add if mode == "exact" else cw.merge
 
         def merge_fn(level, acc, sub):
             nonlocal merge_s, merges
@@ -1744,42 +1618,23 @@ class FederationEngine:
             meter.push(size_of(out))
             return out
 
+        fn = self._bucket_program(mode, share=share, words=words)
+
         def run_bucket(b_idxs, bound):
             """One pow2 shape bucket of one edge group, one dispatch."""
-            nonlocal dispatches
-            Xs, Ds, ns = self._stack_bucket(parts_X, parts_d, b_idxs,
-                                            bound)
             extra = ()
-            if mode == "exact":
-                fn, ctx = self._exact_fused_fn(folder.words), \
-                    enable_x64()
-            elif mode == "masked":
-                fn, ctx = self._masked_fused_fn(share), enable_x64()
+            if mode == "masked":
                 keys = priv.share_keys(b_idxs) if priv.policy.dp else \
                     np.zeros((len(b_idxs), 2), np.uint32)
                 extra = (sess.flat_pad_sums(b_idxs), keys)
-            else:
-                fn, ctx = self._fused_fn(False), contextlib.nullcontext()
-            with ctx:
-                wk = (mode, bound, len(b_idxs))
-                if self.warmup and wk not in warmed:
-                    warmed.add(wk)
-                    jax.block_until_ready(fn(*self._stack_bucket(
-                        parts_X, parts_d, b_idxs, bound), *extra))
-                t0 = time.perf_counter()
-                with self.trace.span("bucket.dispatch",
-                                     bound=int(bound),
-                                     n_clients=len(b_idxs),
-                                     fused=True, mode=mode,
-                                     **self._out_attrs(
-                                         len(b_idxs), bound, m_in, c,
-                                         fold=mode == "float")):
-                    out = fn(Xs, Ds, ns, *extra)
-                    with self.trace.span("client.wait"):
-                        jax.block_until_ready(out)
-            dispatches += 1
-            self._share_times(time_by, b_idxs, ns,
-                              time.perf_counter() - t0)
+            wk = (mode, bound, len(b_idxs))
+            warm = self.warmup and wk not in warmed
+            warmed.add(wk)
+            out = self._dispatch_bucket(
+                fn, parts_X, parts_d, b_idxs, bound, tally, extra=extra,
+                x64=mode != "float", warm=warm, fused=True, mode=mode,
+                **self._out_attrs(len(b_idxs), bound, m_in, c,
+                                  fold=mode == "float"))
             if mode == "exact":
                 return np.asarray(out)
             if mode == "masked":
@@ -1791,36 +1646,21 @@ class FederationEngine:
             """One member's individual pass (stream transport's chunk
             fold, or a masked empty shard's pad-carrying upload), then
             the codec's per-client encode — timed like the loop path."""
-            nonlocal dispatches
             if self.warmup and ("client",) not in warmed:
                 warmed.add(("client",))
                 jax.block_until_ready(
                     self._client_stats(parts_X[i], parts_d[i]))
-            t0 = time.perf_counter()
-            with self.trace.span("client.stats", track="client",
-                                 cid=int(i), mode=mode):
-                st = self._client_stats(parts_X[i], parts_d[i])
-                with self.trace.span("client.wait", track="client"):
-                    jax.block_until_ready(st)
-                if mode == "exact":
-                    st = folder.encode(st)
-                elif mode == "masked":
-                    st = priv.client_encode(int(i), st)
-            time_by[i] = time_by.get(i, 0.0) + \
-                (time.perf_counter() - t0)
-            dispatches += 1
-            return st
+            return self._client_pass(i, parts_X, parts_d, tally,
+                                     encode=encode, mode=mode)
 
         stream = self.transport == "stream"
 
         if self.transport == "mesh":
             def make_leaf(subset):
-                nonlocal dispatches
                 pre, nd = self._hier_mesh_groups(
-                    parts_X, parts_d, tree, subset, mode,
-                    folder.words if mode == "exact" else 0,
+                    parts_X, parts_d, tree, subset, mode, words,
                     time_by, warmed)
-                dispatches += nd
+                tally.dispatches += nd
                 for a in pre.values():
                     meter.push(size_of(a))
 
@@ -1917,9 +1757,7 @@ class FederationEngine:
             t0 = time.perf_counter()
             with self.trace.span("solve", first=salt == 1, mode=mode):
                 stats = folder.decode(agg) if mode == "exact" else agg
-                wire = cw if mode == "masked" else self.wire
-                W = wire.solve(self._release(stats, salt=salt),
-                               self.lam)
+                W = cw.solve(self._release(stats, salt=salt), self.lam)
                 jax.block_until_ready(W)
             coord_s += time.perf_counter() - t0
             return W
@@ -1969,7 +1807,7 @@ class FederationEngine:
         if sel is not None:
             # the flat scoring pass's compute/dispatches ride the same
             # report: selection happened before the tiered commit
-            dispatches += sel["dispatches"]
+            tally.dispatches += sel["dispatches"]
             coord_s += sel["score_s"]
             for i, dt in sel["time_by"].items():
                 time_by[i] = time_by.get(i, 0.0) + dt
@@ -1979,7 +1817,7 @@ class FederationEngine:
             wire_bytes=int(sim["bytes_tiered"]), roles=roles,
             n_samples=sum(int(parts_X[i].shape[0])
                           for i in roles.participants),
-            W_first=W_first, dispatches=dispatches,
+            W_first=W_first, dispatches=tally.dispatches,
             peak_coordinator_bytes=meter.peak, hierarchy=hierarchy,
             contribution=None if sel is None else sel["contribution"])
 
@@ -2207,6 +2045,15 @@ class FederationEngine:
                            # only ever holds ONE replicated aggregate
                            peak_coordinator_bytes=bytes_wire.stats_bytes(
                                n_local, X.shape[1], D.shape[1]))
+
+
+@dataclasses.dataclass
+class _Tally:
+    """One client phase's bookkeeping: each client's measured seconds
+    (``RoundReport.client_times``) and the compiled-call dispatches
+    issued (``RoundReport.dispatches``)."""
+    time_by: dict = dataclasses.field(default_factory=dict)
+    dispatches: int = 0
 
 
 class _PeakMeter:

@@ -235,18 +235,18 @@ class TierTree:
         residency the hierarchy bench meters. Returns the root
         aggregate (None when every edge came back empty).
         """
-        def node(level, idx):
-            if level == 0:
-                return leaf(idx, self.levels[0][idx])
-            acc = None
-            for child in self.levels[level][idx]:
-                sub = node(level - 1, child)
-                if sub is None:
-                    continue
-                acc = sub if acc is None else merge(level, acc, sub)
-            return acc
+        return self._fold_node(self.tiers - 1, 0, leaf, merge)
 
-        return node(self.tiers - 1, 0)
+    def _fold_node(self, level, idx, leaf, merge):
+        # not a recursive closure: that cycle would pin leaf/merge (the engine)
+        if level == 0:
+            return leaf(idx, self.levels[0][idx])
+        acc = None
+        for child in self.levels[level][idx]:
+            sub = self._fold_node(level - 1, child, leaf, merge)
+            if sub is not None:
+                acc = sub if acc is None else merge(level, acc, sub)
+        return acc
 
 
 # --------------------------------------------------------------- failover

@@ -1,11 +1,12 @@
 """The flight recorder's linked spans (DESIGN.md §14): parent links on
-the loop, batched, fused and event-driven paths, the host-time spans
-(``round.prep``, ``bucket.stack``, ``client.wait``, ``ledger.snapshot``,
-``gc``), the collector hook's lifetime, and the profiler annotations
-that put every span on the device trace's clock."""
+the loop, batched, fused, masked fused, tiered and event-driven paths,
+the host-time spans (``round.prep``, ``bucket.stack``, ``client.wait``,
+``ledger.snapshot``, ``gc``), the collector hook's lifetime, and the
+profiler annotations that put every span on the device trace's clock."""
 import gc
 import glob
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -82,9 +83,17 @@ def test_loop_round_links_prep_stats_wait_merge_solve():
                for s in _work(tr))
 
 
-@pytest.mark.parametrize("kw", [{"batch_clients": True}, {"fused": True}],
-                         ids=["batched", "fused"])
-def test_bucket_paths_stack_then_dispatch_then_wait(kw):
+@pytest.mark.parametrize("kw,n_buckets,bytes_out,coord", [
+    ({"batch_clients": True}, 3, False, {"merge": 1}),
+    ({"fused": True}, 3, True, {"merge": 1}),
+    # two edges of two clients: bounds 8 + 32 and 32 + 128; each edge
+    # folds its two buckets, the root folds the two edges
+    ({"topology": "fanout=2,tiers=2,exact=off"}, 4, True,
+     {"tier.fold": 3, "merge": 0}),
+    ({"privacy": "secagg", "fused": True}, 3, False, {"merge": 1}),
+], ids=["batched", "fused", "tiered", "masked"])
+def test_bucket_paths_stack_then_dispatch_then_wait(kw, n_buckets,
+                                                    bytes_out, coord):
     sizes = [5, 30, 31, 70]
     pX, pD = _parts(sizes)
     tr = Tracer()
@@ -93,14 +102,19 @@ def test_bucket_paths_stack_then_dispatch_then_wait(kw):
     (rnd,) = tr.spans_named("round")
     stacks = tr.spans_named("bucket.stack")
     dispatches = tr.spans_named("bucket.dispatch")
-    assert len(stacks) == len(dispatches) == 3     # bounds 8, 32, 128
+    assert len(stacks) == len(dispatches) == n_buckets
     assert all(s.parent == rnd.id for s in stacks + dispatches)
+    # bucket_out_mb.round reads bytes_out where the program's fleet
+    # pass can say what it writes
+    assert all(("bytes_out" in d.attrs) == bytes_out for d in dispatches)
     waits = tr.spans_named("client.wait")
     assert sorted(w.parent for w in waits) == \
         sorted(d.id for d in dispatches)
     assert all(w.track == "coordinator" for w in waits)
-    (merge,) = tr.spans_named("merge")
-    assert merge.parent == rnd.id
+    for name, count in coord.items():
+        spans = tr.spans_named(name)
+        assert len(spans) == count
+        assert all(sp.parent == rnd.id for sp in spans)
 
 
 def test_bucket_stack_bytes_count_pulls_and_stacks():
@@ -170,6 +184,27 @@ def test_gc_pass_in_a_traced_round_is_a_span_and_the_hook_leaves():
     assert _parent(tr, sp).attrs["tick"] == 1
     del eng, tr
     assert gc.callbacks == before
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"fused": True}, {"topology": "fanout=2,tiers=2,exact=off"}],
+    ids=["loop", "fused", "tiered"])
+def test_round_leaves_no_cycle_holding_the_tracer(kw):
+    """A finished round frees its engine and tracer by reference count:
+    one parked in a reference cycle keeps its gc hook in
+    ``gc.callbacks`` until the cyclic collector runs (the tier fold's
+    recursive closure did)."""
+    pX, pD = _parts([5, 30, 31, 70])
+    gc.collect()
+    gc.disable()
+    try:
+        tr = Tracer()
+        ref = weakref.ref(tr)
+        FederationEngine(wire="gram", trace=tr, **kw).run(pX, pD)
+        del tr
+        assert ref() is None
+    finally:
+        gc.enable()
 
 
 def test_profiler_trace_holds_the_program_spans(tmp_path):
